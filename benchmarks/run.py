@@ -76,16 +76,13 @@ BENCH_2.json baselines)
 """
 from __future__ import annotations
 
-import time
-
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import json
 import platform
 import sys
+import time
 from pathlib import Path
+
+import jax
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
@@ -1066,6 +1063,13 @@ def main(argv: list[str] | None = None) -> None:
     if unknown:
         raise SystemExit(f"unknown suites {unknown}; pick from {list(SUITES)}")
 
+    if "linalg" in names:
+        # switches off XLA:CPU async dispatch, which only takes effect
+        # before init_process may create the backends (linalg.ops)
+        import repro.linalg  # noqa: F401
+    from repro.runtime import init_process
+
+    init_process()
     global SMOKE
     SMOKE = args.smoke
     print("name,us_per_call,derived")

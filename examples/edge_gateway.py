@@ -10,10 +10,6 @@ bucket in place, and answers every client with a verified determinant.
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import sys
 from pathlib import Path
 
@@ -24,6 +20,7 @@ import numpy as np
 from repro.configs import SPDCConfig, SPDCGatewayConfig
 from repro.core import ServerFault
 from repro.serve import SPDCGateway
+from repro.runtime import init_process
 
 
 def main():
@@ -31,6 +28,7 @@ def main():
     ap.add_argument("--clients", type=int, default=24)
     ap.add_argument("--servers", type=int, default=2)
     args = ap.parse_args()
+    init_process()
 
     cfg = SPDCGatewayConfig(
         name="demo-gateway",

@@ -23,21 +23,19 @@ with the in-process one.
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 # before any jax dispatch: repro.linalg flips jax_cpu_enable_async_dispatch
 # at import, which only takes effect while the CPU backend doesn't exist yet
 from repro.linalg import SecureLinalg  # noqa: E402
+from repro.runtime import init_process
 
 
 def rbf_cov(x, log_ell, log_sf, log_noise):
@@ -82,6 +80,7 @@ def main():
                     help="also serve the (slogdet, solve) pair through "
                          "the SPDC gateway's op-keyed buckets")
     args = ap.parse_args()
+    init_process()
 
     from repro.api.transport import resolve_transport
 

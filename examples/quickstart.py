@@ -7,10 +7,6 @@ Authenticate(Q3) → Decipher — then a batched stack through the same API.
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import sys
 from pathlib import Path
 
@@ -19,6 +15,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro.core import outsource_determinant
+from repro.runtime import init_process
 
 
 def main():
@@ -30,6 +27,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8,
                     help="size of the batched demo stack (0 to skip)")
     args = ap.parse_args()
+    init_process()
 
     rng = np.random.default_rng(0)
     # a client matrix (well-conditioned, as an outsourcing client can ensure)

@@ -7,10 +7,6 @@ caught by a per-strip secret probe and quarantined (DESIGN.md §8).
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import sys
 from pathlib import Path
 
@@ -21,6 +17,7 @@ import numpy as np
 from repro.api import SPDCClient, ThreadPoolTransport
 from repro.configs import RatelessConfig
 from repro.core.faults import ServerFault
+from repro.runtime import init_process
 
 N = 4
 
@@ -30,6 +27,7 @@ def main():
     ap.add_argument("--n", type=int, default=64)
     ap.add_argument("--batch", type=int, default=6)
     args = ap.parse_args()
+    init_process()
 
     rng = np.random.default_rng(7)
     stack = (rng.standard_normal((args.batch, args.n, args.n))

@@ -7,10 +7,6 @@ tampering worker over a real process boundary.
 """
 import argparse
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import sys
 from pathlib import Path
 
@@ -23,6 +19,7 @@ from repro.api import (
     ThreadPoolTransport,
 )
 from repro.core import ServerFault
+from repro.runtime import init_process
 
 
 def main():
@@ -33,6 +30,7 @@ def main():
                     help="spawn real worker processes (slower to start; "
                          "every message crosses an OS pipe as bytes)")
     args = ap.parse_args()
+    init_process()
 
     rng = np.random.default_rng(0)
     m = rng.standard_normal((args.n, args.n)) + args.n * np.eye(args.n)

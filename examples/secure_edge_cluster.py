@@ -9,19 +9,17 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
+import jax
 import numpy as np
 
 from repro.core import outsource_determinant
 from repro.distrib.spdc_pipeline import pipeline_collective_bytes
+from repro.runtime import init_process
 
 
 def main():
@@ -29,6 +27,7 @@ def main():
     ap.add_argument("--servers", type=int, default=8)
     ap.add_argument("--n", type=int, default=237)  # deliberately awkward size
     args = ap.parse_args()
+    init_process()
     assert args.servers <= len(jax.devices()), (
         f"need {args.servers} devices, have {len(jax.devices())}"
     )

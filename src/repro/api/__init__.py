@@ -38,6 +38,7 @@ from .messages import (
 )
 from .server import EdgeServer
 from .transport import (
+    AcceleratorHeld,
     InlineTransport,
     MultiprocessTransport,
     ShardMapTransport,
@@ -59,7 +60,7 @@ __all__ = [
     "ShardTask", "ShardResult", "TriSolveTask", "TriSolveResult",
     "FaultPlanFrame",
     "Transport", "TransportConfig", "TransportError", "TransportTimeout",
-    "TransportWorkerDied", "TransportProtocolError",
+    "TransportWorkerDied", "TransportProtocolError", "AcceleratorHeld",
     "InlineTransport", "ShardMapTransport",
     "ThreadPoolTransport", "MultiprocessTransport", "SocketTransport",
     "WorkerDaemon", "resolve_transport",

@@ -79,6 +79,7 @@ from .transport import (
     TransportProtocolError,
     TransportTimeout,
     TransportWorkerDied,
+    refuse_spawn_on_accelerator,
     _run_relay,
     serve_frame,
 )
@@ -417,7 +418,8 @@ class SocketTransport(Transport):
     """Connection pool to a fleet of warm worker daemons (module doc).
 
     addresses: daemon endpoints; worker i → addresses[i % len]. Empty →
-        self-host local UDS daemons per worker id on demand.
+        self-host local UDS daemons per worker id on demand (CPU hosts
+        only: under an accelerator construction raises AcceleratorHeld).
     timeout: default per-request deadline; a miss drops the CONNECTION
         (the daemon survives) and raises TransportTimeout.
     connect_timeout: total budget for one connect-with-backoff cycle,
@@ -433,6 +435,8 @@ class SocketTransport(Transport):
         from repro.distrib.rateless import FleetHealth
 
         self.addresses = tuple(addresses)
+        if not self.addresses:
+            refuse_spawn_on_accelerator("self-hosted socket")
         self.timeout = float(timeout)
         self.connect_timeout = float(connect_timeout)
         self.health = FleetHealth()  # reconnect/backoff bookkeeping

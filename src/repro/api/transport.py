@@ -87,6 +87,7 @@ __all__ = [
     "TransportTimeout",
     "TransportWorkerDied",
     "TransportProtocolError",
+    "AcceleratorHeld",
     "InlineTransport",
     "ShardMapTransport",
     "ThreadPoolTransport",
@@ -125,6 +126,26 @@ class TransportProtocolError(TransportError):
     carrying an incompatible protocol/wire version. Unlike a death this
     is not retried — a peer speaking the wrong protocol will speak it
     again — the connection is dropped and the error surfaces typed."""
+
+
+class AcceleratorHeld(TransportError):
+    """A spawning transport was asked for local worker processes while
+    this process holds an accelerator. Each worker imports JAX and would
+    claim the chip, which belongs to one process at a time: it would fail
+    on the chip's lock or hang. Raised at construction, never retried."""
+
+
+def refuse_spawn_on_accelerator(transport: str) -> None:
+    """Raise AcceleratorHeld unless this process's backend is the CPU."""
+    from repro.runtime import on_cpu
+
+    if not on_cpu():
+        raise AcceleratorHeld(
+            f"the {transport} transport spawns worker processes that "
+            f"import JAX, but this process holds the "
+            f"{jax.default_backend()} backend; use the inline or shardmap "
+            "transport here, or socket daemons started on other hosts"
+        )
 
 
 @partial(jax.jit, static_argnames=("num_servers", "faults"))
@@ -500,7 +521,8 @@ class MultiprocessTransport(Transport):
 
     Workers spawn lazily per worker id (first dispatch pays the process +
     jax import + jit cost; a shared instance amortizes it across every
-    later sweep) and inherit the parent's x64 setting.
+    later sweep) and inherit the parent's x64 setting. CPU hosts only:
+    under an accelerator construction raises AcceleratorHeld.
 
     Request discipline: each pipe is strict lock-step request-reply, so
     each WORKER has its own lock (requests to different workers run
@@ -518,6 +540,7 @@ class MultiprocessTransport(Transport):
     def __init__(self, *, timeout: float = 600.0):
         import multiprocessing as mp
 
+        refuse_spawn_on_accelerator(self.name)
         self._ctx = mp.get_context("spawn")
         self._conns: dict[int, object] = {}  #: guarded-by: self._meta
         self._procs: dict[int, object] = {}  #: guarded-by: self._meta

@@ -36,7 +36,6 @@ from .lu import (
     lu_unblocked,
     nserver_comm_model,
     slogdet_from_lu,
-    slogdet_pair_from_lu,
 )
 from .protocol import (
     SPDCBatchResult,
@@ -83,7 +82,7 @@ __all__ = [
     "SPDCInverseResult", "outsource_inverse",
     "CommLog", "det_from_lu", "lu_block_row", "lu_blocked", "lu_diag_factor",
     "lu_nserver", "lu_panel_blocked", "lu_unblocked", "nserver_comm_model",
-    "slogdet_from_lu", "slogdet_pair_from_lu",
+    "slogdet_from_lu",
     "SPDCBatchResult", "SPDCResult", "common_padded_size",
     "outsource_determinant", "outsource_determinant_mixed", "resolve_dtype",
     "flip_sign", "growth_safe_sign",

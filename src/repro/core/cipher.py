@@ -97,12 +97,11 @@ def cipher(
     mode: Mode = "ewd",
     growth_safe: bool = False,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> tuple[jnp.ndarray, CipherMeta]:
     """Cipher(K, M) → X. Returns the ciphertext and the (client-held) meta.
 
-    use_kernel selects the fused Pallas CED kernel (TPU target; interpret
-    mode executes it on CPU). The jnp path is the oracle.
+    use_kernel selects the fused Pallas CED kernel (compiled on a TPU, the
+    Pallas interpreter on the CPU). The jnp path is the oracle.
 
     growth_safe composes odd rotations with a det-tracked exchange flip
     (module docstring / DESIGN.md §6.1) so the no-pivot LU's element
@@ -116,7 +115,7 @@ def cipher(
         from repro.kernels import ops as kops
 
         x = kops.ced(m, jnp.asarray(key.v), k, mode=mode,
-                     growth_safe=growth_safe, interpret=interpret)
+                     growth_safe=growth_safe)
     else:
         x = rot90_cw(ewo(m, jnp.asarray(key.v), mode), k)
         if growth_safe:
@@ -166,7 +165,6 @@ def cipher_batch(
     mode: Mode = "ewd",
     growth_safe: bool = False,
     use_kernel: bool = False,
-    interpret: bool = True,
 ) -> tuple[jnp.ndarray, list[CipherMeta]]:
     """Batched Cipher: (B, n, n) stack + (B, n) stacked blinding vectors.
 
@@ -194,7 +192,7 @@ def cipher_batch(
         for k in sorted(set(ks.tolist())):
             idx = np.nonzero(ks == k)[0]
             xk = kops.ced(m[idx], v[idx], int(k), mode=mode,
-                          growth_safe=growth_safe, interpret=interpret)
+                          growth_safe=growth_safe)
             x = x.at[idx].set(xk)
     else:
         x = _cipher_batch_jnp(m, v, jnp.asarray(ks), mode=mode,
@@ -222,16 +220,27 @@ def equilibrate(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     (..., n, n) input gives (...,)-shaped log2_scale. All-zero rows /
     columns scale by 1 (their max is clamped), leaving det = 0 alone.
     """
+    info = jnp.finfo(x.dtype)
+    bias = info.maxexp - 1
+    bits = jnp.dtype(f"int{info.bits}")
+
     def pow2_exp(maxabs):
         # integer exponent of the power of two nearest the magnitude;
-        # clamp 0 → exponent 0 (scale 1)
+        # clamp 0 → exponent 0 (scale 1), and stay in the normal range
         safe = jnp.where(maxabs > 0, maxabs, 1.0)
-        return jnp.round(jnp.log2(safe)).astype(jnp.int32)
+        e = jnp.round(jnp.log2(safe)).astype(jnp.int32)
+        return jnp.clip(e, 1 - bias, bias - 1)
+
+    def pow2(e):
+        # 2**e from its exponent bits: XLA's exp2 is not exact at every
+        # integer (off by up to 3e-6 on a TPU, 1e-6 on the CPU)
+        return lax.bitcast_convert_type(
+            (e.astype(bits) + bias) << info.nmant, x.dtype)
 
     e_r = pow2_exp(jnp.max(jnp.abs(x), axis=-1))
-    x = x * jnp.exp2(-e_r.astype(x.dtype))[..., :, None]
+    x = x * pow2(-e_r)[..., :, None]
     e_c = pow2_exp(jnp.max(jnp.abs(x), axis=-2))
-    x = x * jnp.exp2(-e_c.astype(x.dtype))[..., None, :]
+    x = x * pow2(-e_c)[..., None, :]
     log2_scale = -(jnp.sum(e_r, axis=-1) + jnp.sum(e_c, axis=-1))
     return x, log2_scale
 

@@ -11,21 +11,20 @@ case split). When the cipher used the growth-safe relayout
 (meta.flipped — DESIGN.md §6.1) the sign law is growth_safe_sign instead.
 
 All arithmetic is done in (sign, log|·|) space to survive large n; the
-log-sum over the factor diagonals is compensated
-(core.lu.slogdet_pair_from_lu) and recombined in float64 HERE, on the
-host — a single float32 cannot represent log|det| ≈ 1000 to the 1e-4
-absolute accuracy float32 protocol runs target. See DESIGN.md §1.1, §6.
+log-sum over the factor diagonals is taken on the host in float64
+(core.lu.slogdet_from_lu) — a single float32 cannot represent
+log|det| ≈ 1000 to the 1e-4 absolute accuracy float32 protocol runs
+target. See DESIGN.md §1.1, §6.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .cipher import CipherMeta
-from .lu import slogdet_pair_from_lu
+from .lu import slogdet_from_lu
 from .prt import growth_safe_sign, rotation_sign, rotation_sign_paper
 from .seed import Seed
 
@@ -48,8 +47,8 @@ class Determinant:
 
     `dtype` records the compute dtype of the factorization that produced
     this determinant — it selects allclose()'s default tolerance. `logabs`
-    itself is always a host float64 (built from the compensated device
-    pair), so the log-space value is meaningful beyond the compute
+    itself is always a host float64 (summed on the host from the factor
+    diagonals), so the log-space value is meaningful beyond the compute
     dtype's own resolution.
     """
 
@@ -187,15 +186,11 @@ def decipher(
     log2_scale: the equilibration exponent sum returned by
     core.cipher.equilibrate (0 when the ciphertext was not equilibrated).
     """
-    sign_x, hi, lo = slogdet_pair_from_lu(l, u)
-    logabs_x = float(hi) + float(lo)  # recombine the pair in float64
+    sign_x, logabs_x = slogdet_from_lu(l, u)
     return _assemble(
-        float(sign_x), logabs_x, seed, meta,
+        float(sign_x), float(logabs_x), seed, meta,
         faithful=faithful, log2_scale=log2_scale, dtype=str(l.dtype),
     )
-
-
-_slogdet_pair_jit = jax.jit(slogdet_pair_from_lu)
 
 
 def decipher_batch(
@@ -209,13 +204,11 @@ def decipher_batch(
 ) -> list[Determinant]:
     """Batched Decipher: (B, n, n) LU factors → one Determinant per matrix.
 
-    The O(B·n) diagonal reduction runs as a single jitted device program;
-    only the O(B) per-matrix Ψ/rotation-sign bookkeeping stays on host.
+    The (B, n) diagonals come to the host once; their float64 log-sum
+    and the per-matrix Ψ/rotation-sign bookkeeping run there.
     log2_scale: per-matrix equilibration exponents, shape (B,).
     """
-    sign_x, hi, lo = _slogdet_pair_jit(l, u)
-    sign_x = np.asarray(sign_x)
-    logabs_x = np.asarray(hi, dtype=np.float64) + np.asarray(lo, np.float64)
+    sign_x, logabs_x = slogdet_from_lu(l, u)
     dtype = str(l.dtype)
     if log2_scale is None:
         log2_scale = np.zeros(len(seeds))

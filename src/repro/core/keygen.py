@@ -65,11 +65,16 @@ def keygen(lambda2: int, seed: Seed, n: int, *, spread: float = 0.5) -> Key:
     """
     if n < 2:
         raise ValueError("blinding vector needs n >= 2")
-    u = _csprng(seed.digest, lambda2, n - 1)
+    u = _csprng(seed.digest, lambda2, n)
     g = float(seed.psi) ** (1.0 / n)
-    logs = (u * 2.0 - 1.0) * spread + np.log2(g)
+    # centred offsets sum to zero, so v_n = Ψ / ∏_{i<n} v_i lands at its
+    # own offset inside the band. Uncentred, v_n absorbs the random walk
+    # of the other n-1 offsets (≈ 9 bits at n=1024), and one entry far
+    # outside the band inflates κ(V⁻¹M) by up to that factor.
+    offsets = (u * 2.0 - 1.0) * spread
+    logs = offsets - offsets.mean() + np.log2(g)
     v = np.empty(n, dtype=np.float64)
-    v[: n - 1] = np.exp2(logs)
+    v[: n - 1] = np.exp2(logs[: n - 1])
     # exact product constraint
     v[n - 1] = float(seed.psi) / float(np.prod(v[: n - 1]))
     # v_i != 1 (paper constraint); measure-zero event, nudge deterministically

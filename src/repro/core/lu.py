@@ -40,6 +40,25 @@ import numpy as np
 from jax import lax
 
 
+#: Precision of every product on the LU, relay, verify and VJP paths. At
+#: default precision a TPU multiplies float32 in bfloat16 passes (2.6e-3
+#: relative error on a v5e, 1.5e-7 at HIGHEST), far outside the rounding
+#: that ε(N) and the log-det budget assume (DESIGN.md §6). XLA:CPU
+#: computes the full product either way, so CPU results are unchanged.
+#: What HIGHEST costs on the chip against HIGH is not measured yet.
+PRECISION = lax.Precision.HIGHEST
+
+
+def precise_matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a @ b at PRECISION."""
+    return jnp.matmul(a, b, precision=PRECISION)
+
+
+def precise_einsum(spec: str, *operands) -> jnp.ndarray:
+    """jnp.einsum at PRECISION."""
+    return jnp.einsum(spec, *operands, precision=PRECISION)
+
+
 # ---------------------------------------------------------------------------
 # unblocked (oracle)
 # ---------------------------------------------------------------------------
@@ -119,7 +138,7 @@ def lu_panel_blocked(
             l_below = _trsm_right_upper(ukk, a[..., s1:, s0:s1])
             a = a.at[..., s0:s1, s1:].set(u_right)
             a = a.at[..., s1:, s0:s1].set(l_below)
-            a = a.at[..., s1:, s1:].add(-(l_below @ u_right))
+            a = a.at[..., s1:, s1:].add(-precise_matmul(l_below, u_right))
     return _split_compact(a)
 
 
@@ -148,7 +167,6 @@ def lu_blocked(
     block: int,
     *,
     use_kernels: bool = False,
-    interpret: bool = True,
     acc_dtype=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Right-looking block LU on (..., n, n). n must be divisible by block.
@@ -178,19 +196,16 @@ def lu_blocked(
         from repro.kernels import ops as kops
 
         def panel(x):
-            return kops.lu_panel(x, interpret=interpret, acc_dtype=acc_dtype)
+            return kops.lu_panel(x, acc_dtype=acc_dtype)
 
         def trsm_l(l, b):
-            return kops.trsm_lower(l, b, interpret=interpret,
-                                   acc_dtype=acc_dtype)
+            return kops.trsm_lower(l, b, acc_dtype=acc_dtype)
 
         def trsm_u(u, b):
-            return kops.trsm_upper_right(u, b, interpret=interpret,
-                                         acc_dtype=acc_dtype)
+            return kops.trsm_upper_right(u, b, acc_dtype=acc_dtype)
 
         def schur(c, l, u_):
-            return kops.schur_update(c, l, u_, interpret=interpret,
-                                     acc_dtype=acc_dtype)
+            return kops.schur_update(c, l, u_, acc_dtype=acc_dtype)
     else:
         panel = lu_diag_factor
 
@@ -202,7 +217,7 @@ def lu_blocked(
         trsm_u = _trsm_right_upper
 
         def schur(c, l, u_):
-            return c - l @ u_
+            return c - precise_matmul(l, u_)
 
     # Work on an nb×nb grid of views. Python loop: nb is static & small.
     blocks = [
@@ -347,7 +362,7 @@ def lu_nserver(
         for k in range(i):
             acc = X[i][k]
             for m in range(k):
-                acc = acc - L[i][m] @ U[m][k]
+                acc = acc - precise_matmul(L[i][m], U[m][k])
             # L_ik U_kk = acc  =>  L_ik = acc @ U_kk^{-1}
             L[i][k] = _trsm_right_upper(U[k][k], acc)
         # Schur update of the diagonal block (corrected U_{ki}); the
@@ -355,13 +370,13 @@ def lu_nserver(
         # full-tile Doolittle on the critical path (DESIGN.md §1.1).
         acc = X[i][i]
         for k in range(i):
-            acc = acc - L[i][k] @ U[k][i]
+            acc = acc - precise_matmul(L[i][k], U[k][i])
         L[i][i], U[i][i] = lu_diag_factor(acc)
         # U_{ij} for j > i
         for j in range(i + 1, N):
             acc = X[i][j]
             for k in range(i):
-                acc = acc - L[i][k] @ U[k][j]
+                acc = acc - precise_matmul(L[i][k], U[k][j])
             U[i][j] = jax.scipy.linalg.solve_triangular(
                 L[i][i], acc, lower=True, unit_diagonal=True
             )
@@ -442,11 +457,11 @@ def lu_block_row(
         for k in range(server):
             kb = k * b
             u_col = u_above[..., :, kb : kb + b]
-            acc = x_row[..., :, kb : kb + b] - l_row @ u_col
+            acc = x_row[..., :, kb : kb + b] - precise_matmul(l_row, u_col)
             ukk = u_above[..., kb : kb + b, kb : kb + b]
             lik = _trsm_right_upper(ukk, acc)
             l_row = l_row.at[..., :, kb : kb + b].set(lik)
-        s = x_row - l_row @ u_above
+        s = x_row - precise_matmul(l_row, u_above)
         sii = s[..., :, s0 : s0 + b]
         lii, _ = lu_diag_factor(sii)
         l_row = l_row.at[..., :, s0 : s0 + b].set(lii)
@@ -464,12 +479,12 @@ def lu_block_row(
     for k in range(server):
         acc = blk(x, server, k)
         for m in range(k):
-            acc = acc - L[m] @ blk(u_above, m, k)
+            acc = acc - precise_matmul(L[m], blk(u_above, m, k))
         L[k] = _trsm_right_upper(blk(u_above, k, k), acc)
         l_row = l_row.at[..., :, k * b : (k + 1) * b].set(L[k])
     acc = blk(x, server, server)
     for k in range(server):
-        acc = acc - L[k] @ blk(u_above, k, server)
+        acc = acc - precise_matmul(L[k], blk(u_above, k, server))
     lii, uii = lu_diag_factor(acc)
     l_row = l_row.at[..., :, s0 : s0 + b].set(lii)
     u_row = jnp.zeros_like(x_row)
@@ -477,7 +492,7 @@ def lu_block_row(
     for j in range(server + 1, N):
         acc = blk(x, server, j)
         for k in range(server):
-            acc = acc - L[k] @ blk(u_above, k, j)
+            acc = acc - precise_matmul(L[k], blk(u_above, k, j))
         uij = jax.scipy.linalg.solve_triangular(
             lii, acc, lower=True, unit_diagonal=True
         )
@@ -488,64 +503,25 @@ def lu_block_row(
 # ---------------------------------------------------------------------------
 # determinant from LU
 # ---------------------------------------------------------------------------
-def _neumaier_sum(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Compensated (Kahan–Babuška/Neumaier) sum over the LAST axis.
-
-    Returns the (hi, lo) pair whose exact value hi + lo carries the sum to
-    ~u² relative error — the lost low-order bits of every addition are
-    accumulated in lo instead of discarded. In float32 a naive sum of n
-    log terms loses ~n·u·|partial-sum| absolute accuracy, which at
-    n = 1024 can exceed the 1e-4 log-space budget; the compensated pair,
-    recombined in float64 on the host, does not. Batch-aware over leading
-    dims; differentiably irrelevant (used only for reporting).
-    """
-    xt = jnp.moveaxis(x, -1, 0)
-    zeros = jnp.zeros(xt.shape[1:], dtype=x.dtype)
-
-    def step(carry, xi):
-        s, c = carry
-        t = s + xi
-        # whichever operand is larger kept its bits; the smaller one's
-        # truncated tail is recovered exactly
-        c = c + jnp.where(jnp.abs(s) >= jnp.abs(xi),
-                          (s - t) + xi, (xi - t) + s)
-        return (t, c), None
-
-    (s, c), _ = lax.scan(step, (zeros, zeros), xt)
-    return s, c
-
-
-def slogdet_pair_from_lu(
-    l: jnp.ndarray, u: jnp.ndarray
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(sign, logabs_hi, logabs_lo) from LU factors — the compensated form.
-
-    log|det| = hi + lo exactly (recombine in float64 on the host: a single
-    float32 cannot even REPRESENT log|det| ≈ 1000 to 1e-4 absolute — its
-    ulp there is 2^-23·1024 ≈ 1.2e-4 — so the split is load-bearing for
-    float32 compute, not an optimization). Decipher consumes this;
-    `slogdet_from_lu` keeps the legacy single-float API.
-    """
-    d = jnp.diagonal(l, axis1=-2, axis2=-1) * jnp.diagonal(u, axis1=-2, axis2=-1)
-    sign = jnp.prod(jnp.sign(d), axis=-1)
-    hi, lo = _neumaier_sum(jnp.log(jnp.abs(d)))
-    return sign, hi, lo
-
-
-def slogdet_from_lu(l: jnp.ndarray, u: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+def slogdet_from_lu(l: jnp.ndarray, u: jnp.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """(sign, log|det|) from LU factors — paper §IV.F.1 in overflow-safe form.
 
     det(X) = Π L_ii · Π U_ii; L is unit-diagonal in our construction but we
     include its diagonal anyway to match the paper's formula. Batch-aware:
-    (..., n, n) factors give (...,)-shaped sign and logabs. The log sum is
-    compensated (slogdet_pair_from_lu) so B×n=1024 float32 stacks don't
-    lose digits; here the pair is recombined in the compute dtype — use
-    the pair form when the caller can recombine in float64.
+    (..., n, n) factors give (...,)-shaped sign and logabs.
+
+    Only the (..., n) diagonals leave the device; the logs and their sum
+    are taken on the host in float64. A float32 log|det| ≈ 10³ cannot hold
+    the 1e-4 budget (its ulp there is 1.2e-4), and the TPU's float32 log is
+    biased by ~1.4e-6 per term, which n terms add up (DESIGN.md §6.4).
     """
-    sign, hi, lo = slogdet_pair_from_lu(l, u)
-    return sign, hi + lo
+    d = (np.asarray(jnp.diagonal(l, axis1=-2, axis2=-1), dtype=np.float64)
+         * np.asarray(jnp.diagonal(u, axis1=-2, axis2=-1), dtype=np.float64))
+    with np.errstate(divide="ignore"):  # a zero pivot: log|det| = -inf
+        logabs = np.sum(np.log(np.abs(d)), axis=-1)
+    return np.prod(np.sign(d), axis=-1), logabs
 
 
-def det_from_lu(l: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
+def det_from_lu(l: jnp.ndarray, u: jnp.ndarray) -> np.ndarray:
     sign, logabs = slogdet_from_lu(l, u)
-    return sign * jnp.exp(logabs)
+    return sign * np.exp(logabs)

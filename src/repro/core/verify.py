@@ -68,28 +68,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .lu import precise_einsum as _einsum
+
 
 def q1(l: jnp.ndarray, u: jnp.ndarray, x: jnp.ndarray, r: jnp.ndarray) -> jnp.ndarray:
     """Gao & Yu's vector check: L(Ur) − Xr. Zero vector iff LU consistent."""
-    ur = jnp.einsum("...ij,...j->...i", u, r)
+    ur = _einsum("...ij,...j->...i", u, r)
     return (
-        jnp.einsum("...ij,...j->...i", l, ur)
-        - jnp.einsum("...ij,...j->...i", x, r)
+        _einsum("...ij,...j->...i", l, ur)
+        - _einsum("...ij,...j->...i", x, r)
     )
 
 
 def q2(l: jnp.ndarray, u: jnp.ndarray, x: jnp.ndarray, r: jnp.ndarray) -> jnp.ndarray:
     """Paper's scalar probabilistic check: (Lᵀr)ᵀ(Ur) − (rᵀX)r."""
-    lt_r = jnp.einsum("...ij,...i->...j", l, r)
-    u_r = jnp.einsum("...ij,...j->...i", u, r)
-    rx = jnp.einsum("...i,...ij->...j", r, x)
+    lt_r = _einsum("...ij,...i->...j", l, r)
+    u_r = _einsum("...ij,...j->...i", u, r)
+    rx = _einsum("...i,...ij->...j", r, x)
     return jnp.sum(lt_r * u_r, axis=-1) - jnp.sum(rx * r, axis=-1)
 
 
 def q3(l: jnp.ndarray, u: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Deterministic diagonal check, per-element abs (the form the paper's
     own correctness proof §V.C.2 uses): Σ_i |(L·U)_ii − x_ii|."""
-    lu_diag = jnp.einsum("...ij,...ji->...i", jnp.tril(l), jnp.triu(u))
+    lu_diag = _einsum("...ij,...ji->...i", jnp.tril(l), jnp.triu(u))
     return jnp.sum(
         jnp.abs(lu_diag - jnp.diagonal(x, axis1=-2, axis2=-1)), axis=-1
     )
@@ -101,7 +103,7 @@ def q3_paper_literal(l: jnp.ndarray, u: jnp.ndarray, x: jnp.ndarray) -> jnp.ndar
     Weaker than q3: opposite-sign per-row errors cancel (see
     tests/test_core_protocol.py::test_q3_literal_cancellation_weakness).
     """
-    lu_diag = jnp.einsum("...ij,...ji->...i", jnp.tril(l), jnp.triu(u))
+    lu_diag = _einsum("...ij,...ji->...i", jnp.tril(l), jnp.triu(u))
     return jnp.abs(
         jnp.sum(lu_diag - jnp.diagonal(x, axis1=-2, axis2=-1), axis=-1)
     )
@@ -194,7 +196,7 @@ def per_server_residuals(
         terms = jnp.abs(q1(l, u, x, r))  # (..., n)
         reduce = jnp.max
     elif method == "q3":
-        lu_diag = jnp.einsum("...ij,...ji->...i", jnp.tril(l), jnp.triu(u))
+        lu_diag = _einsum("...ij,...ji->...i", jnp.tril(l), jnp.triu(u))
         terms = jnp.abs(lu_diag - jnp.diagonal(x, axis1=-2, axis2=-1))
         reduce = jnp.sum
     else:
@@ -298,13 +300,16 @@ def localize(
     num_servers: int,
     eps: float | np.ndarray | None = None,
     rng: np.random.Generator | None = None,
+    r: jnp.ndarray | None = None,
 ) -> tuple[np.ndarray, np.ndarray, int | np.ndarray]:
-    """(server_residual, server_ok, culprit) via the blocked Q1 residual."""
+    """(server_residual, server_ok, culprit) via the blocked Q1 residual,
+    on probe `r` when given (else one drawn from `rng`)."""
     n = x.shape[-1]
     if eps is None:
         eps = epsilon(num_servers, n, x, dtype=x.dtype)
         eps = eps * growth_estimate(u, x)
-    sres = per_server_residuals(l, u, x, num_servers=num_servers, rng=rng)
+    sres = per_server_residuals(l, u, x, num_servers=num_servers, rng=rng,
+                                r=r)
     eps_col = np.asarray(eps)[..., None] if np.ndim(eps) else eps
     sok = sres <= eps_col
     return sres, sok, _first_culprit(sok)
@@ -402,8 +407,13 @@ def authenticate(
             widened_eps = epsilon(num_servers, n, x, dtype=x.dtype) \
                 * growth_estimate(u, x)
         loc_eps = widened_eps
+        # a q1 rejection is attributed on the probe that rejected: the
+        # blocks partition that residual, so some block exceeds ε. A
+        # fresh probe can miss a fault at the detection floor and leave
+        # recovery nothing to heal.
         sres, sok, culprit = localize(
-            l, u, x, num_servers=num_servers, eps=loc_eps, rng=rng
+            l, u, x, num_servers=num_servers, eps=loc_eps, rng=rng,
+            r=r if method == "q1" else None,
         )
         verdict.server_residual = sres
         verdict.server_ok = sok

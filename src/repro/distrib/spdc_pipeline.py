@@ -38,9 +38,10 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, pcast, shard_map
+from repro.core.lu import precise_matmul
 
 
 def _factor_diag(a: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -114,7 +115,8 @@ def _server_program(x_blk: jnp.ndarray, *, n: int, b: int, num_servers: int,
             # recomputing the full (b,n) product (§Perf C2 — 16x fewer flops
             # in the L-row loop)
             u_col = lax.dynamic_slice(u_buf, (zero, zero, kb), (B, n, b))
-            acc = lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b)) - l_row @ u_col
+            acc = (lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b))
+                   - precise_matmul(l_row, u_col))
             ukk = lax.dynamic_slice(u_buf, (zero, kb, kb), (B, b, b))
             lik = _trsm_right_upper_b(ukk, acc)
             return lax.dynamic_update_slice(l_row, lik, (zero, zero, kb))
@@ -122,7 +124,7 @@ def _server_program(x_blk: jnp.ndarray, *, n: int, b: int, num_servers: int,
         l_row = lax.fori_loop(0, my_id, lblk, l_row)
 
         # --- Schur update of the whole row, blocked-panel LU of the diag ---
-        s = x_row - l_row @ u_buf
+        s = x_row - precise_matmul(l_row, u_buf)
         ib = (my_id * b).astype(jnp.int32)
         sii = lax.dynamic_slice(s, (zero, zero, ib), (B, b, b))
         lii, uii = _factor_diag(sii)
@@ -154,7 +156,7 @@ def _server_program(x_blk: jnp.ndarray, *, n: int, b: int, num_servers: int,
     l_row0 = jnp.zeros((B, b, n), dtype=x_row.dtype)
     u_row0 = jnp.zeros((B, b, n), dtype=x_row.dtype)
     # carries become device-varying inside the loop; mark them so upfront
-    u_buf0, l_row0, u_row0 = pcast(
+    u_buf0, l_row0, u_row0 = lax.pcast(
         (u_buf0, l_row0, u_row0), (axis,), to="varying"
     )
     _, l_row, u_row = lax.fori_loop(
@@ -188,13 +190,14 @@ def _server_program_exact(x_blk: jnp.ndarray, *, n: int, b: int,
         def lblk(k, l_row):
             kb = (k * b).astype(jnp.int32)
             u_col = lax.dynamic_slice(u_buf, (zero, zero, kb), (B, n, b))
-            acc = lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b)) - l_row @ u_col
+            acc = (lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b))
+                   - precise_matmul(l_row, u_col))
             ukk = lax.dynamic_slice(u_buf, (zero, kb, kb), (B, b, b))
             lik = _trsm_right_upper_b(ukk, acc)
             return lax.dynamic_update_slice(l_row, lik, (zero, zero, kb))
 
         l_row = lax.fori_loop(0, my_id, lblk, l_row)
-        s = x_row - l_row @ u_buf
+        s = x_row - precise_matmul(l_row, u_buf)
         ib = (my_id * b).astype(jnp.int32)
         sii = lax.dynamic_slice(s, (zero, zero, ib), (B, b, b))
         lii, _ = _factor_diag(sii)
@@ -209,7 +212,7 @@ def _server_program_exact(x_blk: jnp.ndarray, *, n: int, b: int,
     u_buf = jnp.zeros((B, n, n), dtype=x_row.dtype)
     l_row = jnp.zeros((B, b, n), dtype=x_row.dtype)
     u_row = jnp.zeros((B, b, n), dtype=x_row.dtype)
-    u_buf, l_row, u_row = pcast(
+    u_buf, l_row, u_row = lax.pcast(
         (u_buf, l_row, u_row), (axis,), to="varying"
     )
     for t in range(num_servers):
@@ -244,10 +247,10 @@ def _server_program_stream(x_blk: jnp.ndarray, *, n: int, b: int,
 
     l_row = jnp.zeros((B, b, n), dtype=x_row.dtype)
     u_row = jnp.zeros((B, b, n), dtype=x_row.dtype)
-    l_row, u_row = pcast((l_row, u_row), (axis,), to="varying")
+    l_row, u_row = lax.pcast((l_row, u_row), (axis,), to="varying")
     # _stream_rows[t] = rows received before round t ((B, t·b, n), static)
     _stream_rows = [
-        pcast(jnp.zeros((B, t * b, n), dtype=x_row.dtype), (axis,),
+        lax.pcast(jnp.zeros((B, t * b, n), dtype=x_row.dtype), (axis,),
               to="varying")
         for t in range(num_servers)
     ]
@@ -262,14 +265,14 @@ def _server_program_stream(x_blk: jnp.ndarray, *, n: int, b: int,
                 kb = (k * b).astype(jnp.int32)
                 u_col = lax.dynamic_slice(u_recv, (zero, zero, kb), (B, tb, b))
                 acc = lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b)) \
-                    - l_row[:, :, :tb] @ u_col
+                    - precise_matmul(l_row[:, :, :tb], u_col)
                 ukk = lax.dynamic_slice(u_recv, (zero, kb, kb), (B, b, b))
                 lik = _trsm_right_upper_b(ukk, acc)
                 return lax.dynamic_update_slice(l_row, lik, (zero, zero, kb))
 
             if t:
                 l_row = lax.fori_loop(0, t, lblk, l_row)
-                s = x_row - l_row[:, :, :tb] @ u_recv
+                s = x_row - precise_matmul(l_row[:, :, :tb], u_recv)
             else:
                 s = x_row
             ib = jnp.asarray(t * b, jnp.int32)
@@ -320,10 +323,11 @@ def _compiled_pipeline(program: str, n: int, batch: int | None,
     reuse the compiled executable instead of re-tracing a fresh shard_map.
     """
     devs = tuple(jax.devices()[:num_servers])
-    mesh = make_mesh((num_servers,), (axis,), devices=devs)
+    mesh = jax.make_mesh((num_servers,), (axis,),
+                         axis_types=(AxisType.Auto,), devices=devs)
     b = n // num_servers
     spec = P(None, axis, None) if batch is not None else P(axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_PROGRAMS[program], n=n, b=b, num_servers=num_servers,
                 axis=axis, faults=faults),
         mesh=mesh,
@@ -380,16 +384,18 @@ def lu_nserver_shardmap(
     batch = x.shape[0] if x.ndim == 3 else None
 
     if mesh is None:
-        if len(jax.devices()) < num_servers:
+        devices = jax.devices()
+        if len(devices) < num_servers:
             raise ValueError(
-                f"need {num_servers} devices, have {len(jax.devices())} "
-                "(set --xla_force_host_platform_device_count)"
+                f"the shard_map pipeline needs one device per server: "
+                f"N={num_servers}, but the {devices[0].platform} backend "
+                f"has {len(devices)} device(s)"
             )
         fn = _compiled_pipeline(program, n, batch, num_servers, axis, faults)
     else:
         b = n // num_servers
         spec = P(None, axis, None) if batch is not None else P(axis, None)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             partial(_PROGRAMS[program], n=n, b=b, num_servers=num_servers,
                     axis=axis, faults=faults),
             mesh=mesh,

@@ -8,9 +8,14 @@ it costs zero extra bandwidth (vs. a naive scale-pass + rotate-pass at 2×
 traffic). Arithmetic intensity is 1 flop / 8 bytes (f64) — purely
 memory-bound, so halving traffic halves cipher latency.
 
-Tiles are square (b×b, b a multiple of the 128-lane for the TPU target);
-the in-tile quarter-turn is a (sublane,lane) transpose + flip, supported by
-the Mosaic relayout path on TPU and exact in interpret mode.
+Tiles are square (b×b, b a multiple of the 128-lane for the TPU target).
+The in-tile quarter-turn is composed from transposes and row reversals:
+rot_cw(T) = flipud(T)ᵀ, rot_ccw(T) = flipud(Tᵀ), rot180(T) =
+flipud(flipud(Tᵀ)ᵀ). Mosaic has no lowering for `rev`, so a row reversal
+is b single-row copies between VMEM buffers — pure data movement, exact.
+
+n not a multiple of the tile is zero-padded to one (blinding entries of
+the pad are 1) and the rotated window cropped back out of the result.
 
 Batch (DESIGN.md §3): a (B, n, n) stack adds a leading batch grid axis —
 grid (B, nb, nb), each program ciphers one tile of one matrix; the
@@ -25,21 +30,45 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import on_cpu
 
 
-def _ced_kernel(m_ref, v_ref, o_ref, *, k: int, mode: str,
+def _flip_rows(x, buf_ref, out_ref):
+    """out_ref[...] = x[::-1] (x a (b, b) value; buf_ref scratch)."""
+    b = x.shape[0]
+    buf_ref[...] = x
+
+    def copy_row(r, carry):
+        out_ref[pl.ds(r, 1), :] = buf_ref[pl.ds(b - 1 - r, 1), :]
+        return carry
+
+    lax.fori_loop(0, b, copy_row, 0)
+
+
+def _ced_kernel(m_ref, v_ref, o_ref, buf_ref, rev_ref, *, k: int, mode: str,
                 growth_safe: bool):
     tile = m_ref[...]
     vcol = v_ref[...]  # (b, 1) slice of the blinding vector for these rows
     scaled = tile / vcol if mode == "ewd" else tile * vcol
-    axes = (tile.ndim - 2, tile.ndim - 1)
-    if growth_safe and k % 2 == 1:
+    k = k % 4
+    if k == 0:
+        o_ref[...] = scaled
+    elif growth_safe and k % 2 == 1:
         # odd rotation ∘ exchange flip = transpose, in-tile and in the
         # index map alike (core.cipher growth-safe relayout)
-        o_ref[...] = jnp.swapaxes(scaled, *axes)
+        o_ref[...] = scaled.T
+    elif k == 1:
+        _flip_rows(scaled, buf_ref, rev_ref)
+        o_ref[...] = rev_ref[...].T
+    elif k == 3:
+        _flip_rows(scaled.T, buf_ref, o_ref)
     else:
-        o_ref[...] = jnp.rot90(scaled, k=-(k % 4), axes=axes)
+        _flip_rows(scaled.T, buf_ref, rev_ref)
+        _flip_rows(rev_ref[...].T, buf_ref, o_ref)
 
 
 def _out_index_map(k: int, nb: int, *, batched: bool, growth_safe: bool):
@@ -64,6 +93,22 @@ def _out_index_map(k: int, nb: int, *, batched: bool, growth_safe: bool):
     return rot
 
 
+def _crop(x, n: int, k: int, growth_safe: bool):
+    """The n×n window of a ciphered zero-padded tile grid that holds the
+    rotated matrix (the pad sat bottom/right before the rotation)."""
+    p = x.shape[-1]
+    k = k % 4
+    if k == 0 or (growth_safe and k % 2 == 1):
+        rows, cols = slice(0, n), slice(0, n)
+    elif k == 1:
+        rows, cols = slice(0, n), slice(p - n, p)
+    elif k == 2:
+        rows, cols = slice(p - n, p), slice(p - n, p)
+    else:
+        rows, cols = slice(p - n, p), slice(0, n)
+    return x[..., rows, cols]
+
+
 @partial(jax.jit,
          static_argnames=("k", "mode", "block", "interpret", "growth_safe"))
 def ced(
@@ -73,49 +118,50 @@ def ced(
     *,
     mode: str = "ewd",
     block: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
     growth_safe: bool = False,
 ) -> jnp.ndarray:
     """Fused Cipher: rot90_cw^k(EWO(m, v)) for (n, n) or (B, n, n).
 
-    n must be divisible by block (callers pad via core.augment first when
-    needed); otherwise the largest power-of-two divisor is used.
-    growth_safe composes odd rotations with the exchange flip (the
-    composite is a transpose — still a single fused HBM pass, the index
-    map just changes; core.cipher semantics, DESIGN.md §6.1).
+    n not divisible by block is zero-padded to the tile grid and cropped
+    back (module docstring). growth_safe composes odd rotations with the
+    exchange flip (the composite is a transpose — still a single fused
+    HBM pass, the index map just changes; core.cipher semantics,
+    DESIGN.md §6.1). interpret=None runs the kernel through the Pallas
+    interpreter on the CPU backend and compiled (Mosaic) everywhere else.
     """
+    if interpret is None:
+        interpret = on_cpu()
     n = m.shape[-1]
-    if n % block != 0:
-        block = 1
-        while block * 2 <= n and n % (block * 2) == 0:
-            block *= 2
-    nb = n // block
     batched = m.ndim == 3
+    v = v.reshape(*m.shape[:-1], 1).astype(m.dtype)
+    pad = -n % block
+    if pad:
+        lead = [(0, 0)] if batched else []
+        m = jnp.pad(m, lead + [(0, pad), (0, pad)])
+        v = jnp.pad(v, lead + [(0, pad), (0, 0)], constant_values=1)
+    p = n + pad
+    nb = p // block
     if batched:
-        B = m.shape[0]
-        grid = (B, nb, nb)
-        in_specs = [
-            pl.BlockSpec((1, block, block), lambda b, i, j: (b, i, j)),
-            pl.BlockSpec((1, block, 1), lambda b, i, j: (b, i, 0)),
-        ]
-        out_shape = jax.ShapeDtypeStruct((B, n, n), m.dtype)
-        vv = v.reshape(B, n, 1).astype(m.dtype)
+        grid = (m.shape[0], nb, nb)
+        tile_spec = pl.BlockSpec((pl.squeezed, block, block),
+                                 lambda b, i, j: (b, i, j))
+        v_spec = pl.BlockSpec((pl.squeezed, block, 1),
+                              lambda b, i, j: (b, i, 0))
     else:
         grid = (nb, nb)
-        in_specs = [
-            pl.BlockSpec((block, block), lambda i, j: (i, j)),
-            pl.BlockSpec((block, 1), lambda i, j: (i, 0)),
-        ]
-        out_shape = jax.ShapeDtypeStruct((n, n), m.dtype)
-        vv = v.reshape(n, 1).astype(m.dtype)
-    return pl.pallas_call(
+        tile_spec = pl.BlockSpec((block, block), lambda i, j: (i, j))
+        v_spec = pl.BlockSpec((block, 1), lambda i, j: (i, 0))
+    out = pl.pallas_call(
         partial(_ced_kernel, k=k, mode=mode, growth_safe=growth_safe),
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct(m.shape, m.dtype),
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[tile_spec, v_spec],
         out_specs=pl.BlockSpec(
-            in_specs[0].block_shape,
+            tile_spec.block_shape,
             _out_index_map(k, nb, batched=batched, growth_safe=growth_safe),
         ),
+        scratch_shapes=[pltpu.VMEM((block, block), m.dtype)] * 2,
         interpret=interpret,
-    )(m, vv)
+    )(m, v)
+    return _crop(out, n, k, growth_safe) if pad else out

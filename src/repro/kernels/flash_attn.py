@@ -20,6 +20,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime import on_cpu
+
 NEG_INF = -1e30
 
 
@@ -86,9 +88,11 @@ def flash_attention(
     scale: float | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) with Hq % Hkv == 0."""
+    if interpret is None:
+        interpret = on_cpu()
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     g = hq // hkv

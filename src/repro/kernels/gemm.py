@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.runtime import on_cpu
+
 
 def _schur_kernel(c_ref, a_ref, b_ref, o_ref, *, acc_dtype):
     # contraction index is the innermost grid axis: 2 for (i,j,k) grids,
@@ -51,7 +53,7 @@ def schur_update(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
     acc_dtype=None,
 ) -> jnp.ndarray:
     """C − A @ B with (M,K)@(K,N) tiling; batched over a leading stack dim.
@@ -63,6 +65,8 @@ def schur_update(
     f64 accumulation needs a backend with f64 support (CPU/GPU, or
     interpret mode); TPU Mosaic callers should stay ≤ f32.
     """
+    if interpret is None:
+        interpret = on_cpu()
     m, kdim = a.shape[-2:]
     n = b.shape[-1]
     bm = _fit_block(m, bm)

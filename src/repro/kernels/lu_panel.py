@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.runtime import on_cpu
+
 
 def _lu_panel_kernel(x_ref, o_ref, *, acc_dtype=None):
     a = x_ref[...]
@@ -49,13 +51,15 @@ def _lu_panel_kernel(x_ref, o_ref, *, acc_dtype=None):
 
 
 @partial(jax.jit, static_argnames=("interpret", "acc_dtype"))
-def lu_panel_compact(x: jnp.ndarray, *, interpret: bool = True,
+def lu_panel_compact(x: jnp.ndarray, *, interpret: bool | None = None,
                      acc_dtype=None) -> jnp.ndarray:
     """Compact LU of one panel, or of a (B, b, b) stack via a batch grid
     axis (one panel per program instance — DESIGN.md §3). acc_dtype
     selects the mixed variant: the b-step elimination runs in the wider
     dtype in VMEM and the compact form stores at x.dtype (DESIGN.md §6.4;
     f64 accumulation needs a f64-capable backend or interpret mode)."""
+    if interpret is None:
+        interpret = on_cpu()
     b = x.shape[-1]
     kern = partial(_lu_panel_kernel, acc_dtype=acc_dtype)
     if x.ndim == 3:

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.runtime import on_cpu
+
 
 def _trsm_lower_kernel(l_ref, b_ref, o_ref, *, acc_dtype=None):
     l = l_ref[...]
@@ -73,12 +75,14 @@ def _trsm_upper_right_kernel(u_ref, b_ref, o_ref, *, acc_dtype=None):
 @partial(jax.jit, static_argnames=("col_block", "interpret", "acc_dtype"))
 def trsm_lower(
     l: jnp.ndarray, b: jnp.ndarray, *, col_block: int = 256,
-    interpret: bool = True, acc_dtype=None,
+    interpret: bool | None = None, acc_dtype=None,
 ) -> jnp.ndarray:
     """Solve L X = B for X; grid over column tiles of B. A (B, n, n) /
     (B, n, m) stack adds a leading batch grid axis (DESIGN.md §3).
     acc_dtype selects the mixed variant: the elimination runs in the wider
     dtype in VMEM, the output tile stores at b.dtype (DESIGN.md §6.4)."""
+    if interpret is None:
+        interpret = on_cpu()
     n, m = b.shape[-2:]
     cb = min(col_block, m)
     while m % cb != 0:
@@ -113,11 +117,13 @@ def trsm_lower(
 @partial(jax.jit, static_argnames=("row_block", "interpret", "acc_dtype"))
 def trsm_upper_right(
     u: jnp.ndarray, b: jnp.ndarray, *, row_block: int = 256,
-    interpret: bool = True, acc_dtype=None,
+    interpret: bool | None = None, acc_dtype=None,
 ) -> jnp.ndarray:
     """Solve Z U = B for Z; grid over row tiles of B. A (B, n, n) /
     (B, m, n) stack adds a leading batch grid axis (DESIGN.md §3).
     acc_dtype: mixed variant, as trsm_lower."""
+    if interpret is None:
+        interpret = on_cpu()
     m, n = b.shape[-2:]
     rb = min(row_block, m)
     while m % rb != 0:

@@ -163,8 +163,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: Path) -> dict:
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes
         ),
     }
-    from repro.compat import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     hc = analyze_hlo(hlo)  # trip-count-corrected (see hlo_cost.py docstring)
 
@@ -230,9 +229,7 @@ def run_spdc_cell(mesh_name: str, out_dir: Path, n: int = 8192) -> dict:
     from repro.distrib.spdc_pipeline import _server_program
     from jax.sharding import PartitionSpec as P
     N = mesh.shape["model"]
-    from repro.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_server_program, n=n, b=n // N, num_servers=N, axis="model"),
         mesh=mesh, in_specs=P("model", None),
         out_specs=(P("model", None), P("model", None)),
@@ -242,8 +239,7 @@ def run_spdc_cell(mesh_name: str, out_dir: Path, n: int = 8192) -> dict:
     compiled = lowered.compile()
     compile_s = time.time() - t0
     mem = compiled.memory_analysis()
-    from repro.compat import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hc = analyze_hlo(compiled.as_text())
     rl = analyze(
         arch="spdc-lu", shape=f"n{n}", mesh_name=mesh_name,

@@ -12,8 +12,12 @@ first device query.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def _auto_mesh(shape, axes, devices) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -30,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import"
         )
-    return make_mesh(shape, axes, devices=devs[:need])
+    return _auto_mesh(shape, axes, devs[:need])
 
 
 def make_smoke_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
@@ -38,4 +42,4 @@ def make_smoke_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
     need = 1
     for s in shape:
         need *= s
-    return make_mesh(shape, axes, devices=jax.devices()[:need])
+    return _auto_mesh(shape, axes, jax.devices()[:need])

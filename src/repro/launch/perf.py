@@ -78,9 +78,7 @@ def run_spdc_variant(mesh_name, relay, n, tag):
     N = mesh.shape["model"]
     prog = _PROGRAMS[relay if isinstance(relay, str) else
                      ("exact" if relay else "baseline")]
-    from repro.compat import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(prog, n=n, b=n // N, num_servers=N, axis="model"),
         mesh=mesh, in_specs=P("model", None),
         out_specs=(P("model", None), P("model", None)),

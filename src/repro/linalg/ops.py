@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.lu import precise_matmul
+
 from .session import LinalgSession
 
 __all__ = [
@@ -96,26 +98,14 @@ def _disable_cpu_async_dispatch() -> None:
     backend already exists the update is a silent no-op upstream, so warn
     loudly instead of deadlocking quietly later.
     """
-    # the option is registered as a Flag, not a State: jax.config.update
-    # accepts it but plain attribute reads raise AttributeError, so the
-    # idempotence check must go through the holder table
-    name = "jax_cpu_enable_async_dispatch"
-    current = getattr(jax.config, name, None)
-    if current is None:
-        try:
-            current = jax.config._value_holders[name].value
-        except (AttributeError, KeyError):
-            return  # option absent on this jax version
-    if not current:
-        return  # already off (this guard earlier, or the user)
-    jax.config.update(name, False)
-    try:
-        import jax._src.xla_bridge as _xb
+    # the option is a Flag, not a State: jax.config.update accepts it but
+    # jax.config has no attribute to read it back through
+    from jax._src import xla_bridge as _xb
 
-        late = bool(_xb._backends)
-    except Exception:
-        late = False
-    if late:
+    if not _xb._CPU_ENABLE_ASYNC_DISPATCH.value:
+        return  # already off (this guard earlier, or the user)
+    jax.config.update("jax_cpu_enable_async_dispatch", False)
+    if _xb._backends:
         import warnings
 
         warnings.warn(
@@ -159,9 +149,23 @@ _HOST_POOL = concurrent.futures.ThreadPoolExecutor(
 
 
 def _on_host_thread(fn):
+    """Run `fn` on _HOST_POOL under the caller's default device.
+
+    jax runs a pure_callback with the host CPU as the default device, so
+    the protocol programs the callback dispatches run on the CPU while
+    the device program that called it waits. That default is thread-local
+    and would not survive the hop: on a TPU the protocol would then queue
+    its programs on the chip behind the very program blocked on them.
+    """
     @functools.wraps(fn)
     def wrapper(*args):
-        return _HOST_POOL.submit(fn, *args).result()
+        device = jax.config.jax_default_device
+
+        def call():
+            with jax.default_device(device):
+                return fn(*args)
+
+        return _HOST_POOL.submit(call).result()
 
     return wrapper
 
@@ -259,7 +263,7 @@ def _solve_bwd(ctx, res, zbar):
     if z.ndim == 1:
         abar = -jnp.outer(bbar, z)
     else:
-        abar = -bbar @ z.T
+        abar = -precise_matmul(bbar, z.T)
     return abar, bbar
 
 
@@ -311,7 +315,7 @@ def _inv_fwd(ctx, a):
 def _inv_bwd(ctx, y, ybar):
     # d(A⁻¹) = −A⁻¹ dA A⁻¹  ⇒  Ā = −Yᵀ Ȳ Yᵀ: pure jax-land, the wide
     # round already ran (and is cached) in the forward pass
-    return (-(y.T @ ybar @ y.T),)
+    return (-precise_matmul(precise_matmul(y.T, ybar), y.T),)
 
 
 _inv.defvjp(_inv_fwd, _inv_bwd)

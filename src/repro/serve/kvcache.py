@@ -89,9 +89,7 @@ def cache_logical_specs(cfg, cache_tree) -> dict:
             base = (None,) + base
         return base
 
-    from repro.compat import tree_map_with_path
-
-    return tree_map_with_path(spec_for, cache_tree)
+    return jax.tree.map_with_path(spec_for, cache_tree)
 
 
 def merge_cache_updates(old: dict, upd: dict) -> dict:

@@ -34,9 +34,7 @@ import numpy as np
 
 
 def _flatten_with_paths(tree):
-    from repro.compat import tree_flatten_with_path
-
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(k) for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]
     return paths, leaves, treedef

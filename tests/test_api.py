@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.api import (
+    AcceleratorHeld,
     BoundaryViolation,
     EdgeServer,
     FaultPlanFrame,
@@ -379,6 +380,23 @@ def test_resolve_transport_rules():
         resolve_transport("threadpool", distributed=True)
     with pytest.raises(ValueError, match="conflicts"):
         resolve_transport(inst, distributed=True)
+
+
+def test_spawning_transports_refuse_an_accelerator(monkeypatch):
+    """Under an accelerator the multiprocess and self-hosted socket
+    transports raise AcceleratorHeld at construction: each spawned worker
+    would import JAX and wait on the chip this process holds. A socket
+    client of remote daemons spawns nothing and stays allowed."""
+    import repro.runtime
+    from repro.api import SocketTransport
+
+    monkeypatch.setattr(repro.runtime, "on_cpu", lambda: False)
+    with pytest.raises(AcceleratorHeld, match="multiprocess"):
+        MultiprocessTransport()
+    with pytest.raises(AcceleratorHeld, match="self-hosted socket"):
+        SocketTransport()
+    assert issubclass(AcceleratorHeld, TransportError)
+    SocketTransport(addresses=("tcp://127.0.0.1:9",)).close()
 
 
 def test_transport_config_rules():

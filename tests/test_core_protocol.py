@@ -42,6 +42,18 @@ def test_keygen_product_constraint(n):
     np.testing.assert_allclose(np.prod(key.v), seed.psi, rtol=1e-9)
 
 
+@pytest.mark.parametrize("n", [64, 1024])
+def test_keygen_last_entry_stays_in_band(n):
+    """v_n absorbs the product constraint without leaving the band the
+    others are drawn from; were it left to absorb their random walk (~9
+    bits at n=1024), κ(V⁻¹M) would grow by that factor."""
+    seed = seedgen(128, _wellcond(n))
+    v = keygen(128, seed, n).v
+    off = np.log2(v / float(seed.psi) ** (1.0 / n))
+    # spread 0.5, plus the centring shift (the mean offset, ~0.29/√n)
+    assert np.max(np.abs(off)) <= 0.75
+
+
 # -------------------------------------------------------------------- cipher
 @pytest.mark.parametrize("mode", ["ewd", "ewm"])
 def test_cipher_det_relation(mode):

@@ -52,10 +52,10 @@ def test_shardmap_hlo_is_one_way():
     from repro.distrib.spdc_pipeline import _server_program
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_smoke_mesh
 
-    mesh = make_mesh((servers,), ("servers",), devices=jax.devices()[:servers])
-    fn = shard_map(
+    mesh = make_smoke_mesh((servers,), ("servers",))
+    fn = jax.shard_map(
         partial(_server_program, n=n, b=n // servers, num_servers=servers,
                 axis="servers"),
         mesh=mesh, in_specs=P("servers", None),
@@ -87,9 +87,9 @@ def test_comm_model_overcount_bounded():
 
 # ----------------------------------------------------------- sharding rules
 def test_rules_head_fallback():
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_smoke_mesh
 
-    mesh = make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+    mesh = make_smoke_mesh((2, 4))
     r1 = make_rules(mesh, num_heads=8, num_kv_heads=4)
     assert r1.shard_heads and r1.shard_kv
     r2 = make_rules(mesh, num_heads=6, num_kv_heads=1)  # 6 % 4 != 0
@@ -117,9 +117,9 @@ def test_sharded_train_step_runs():
     from jax.sharding import NamedSharding
 
     cfg = smoke_config("tinyllama-1.1b")
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_smoke_mesh
 
-    mesh = make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+    mesh = make_smoke_mesh((2, 4))
     rules = make_rules(mesh, num_heads=cfg.num_heads,
                        num_kv_heads=cfg.num_kv_heads)
     with use_rules(rules):

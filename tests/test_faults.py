@@ -172,6 +172,19 @@ def test_localize_names_the_faulty_server(honest_lu, kind, mode, target):
         assert sok[:s].all()
 
 
+def test_q1_reject_is_localized_on_the_rejecting_probe(honest_lu):
+    """A q1 rejection blames a server on the same probe: the blocked
+    residuals partition the global one, so a fault at the detection floor
+    cannot pass a fresh probe and leave recovery nothing to heal."""
+    a, l, u = honest_lu
+    lf, uf = apply_faults(l, u, (ServerFault(server=2),), num_servers=N)
+    v = authenticate(lf, uf, a, num_servers=N, method="q1",
+                     rng=np.random.default_rng(3))
+    assert not v.ok
+    assert np.max(v.server_residual) == v.residual
+    assert v.culprit == 2
+
+
 def test_localize_clean_run_blames_nobody(honest_lu):
     a, l, u = honest_lu
     sres, sok, culprit = localize(l, u, a, num_servers=N)

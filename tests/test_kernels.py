@@ -26,6 +26,29 @@ def test_ced_kernel(n, block, k, mode):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-12)
 
 
+@pytest.mark.parametrize("shape", [(100, 100), (128, 128), (2, 100, 100)])
+@pytest.mark.parametrize("growth_safe", [False, True])
+@pytest.mark.parametrize("k", [0, 1, 2, 3])
+def test_ced_every_rotation_and_padded_tile(shape, growth_safe, k):
+    """Every quarter-turn, with and without the growth-safe flip, at the
+    TPU tile (128) and at an n it does not divide (zero-padded to the
+    tile grid, then cropped): exactly the jnp cipher's data movement."""
+    from repro.core.cipher import _flip_rotated, ewo
+    from repro.core.prt import rot90_cw
+
+    m = _rand(shape, seed=k)
+    v = jnp.asarray(np.random.default_rng(1).uniform(0.5, 2.0, shape[:-1]))
+    got = ops.ced(m, v, k, growth_safe=growth_safe)
+    if len(shape) == 3:
+        want = jnp.stack([rot90_cw(ewo(m[i], v[i], "ewd"), k)
+                          for i in range(shape[0])])
+    else:
+        want = rot90_cw(ewo(m, v, "ewd"), k)
+    if growth_safe:
+        want = _flip_rotated(want, k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_ced_dtypes(dtype):
     m = _rand((16, 16), dtype=dtype)

@@ -29,9 +29,7 @@ def test_hlo_cost_counts_scan_trip_counts():
     x = jnp.zeros((64, 128), jnp.float32)
     w = jnp.zeros((128, 128), jnp.float32)
     compiled = jax.jit(f).lower(x, w).compile()
-    from repro.compat import cost_analysis_dict
-
-    raw = cost_analysis_dict(compiled).get("flops", 0.0)
+    raw = compiled.cost_analysis().get("flops", 0.0)
     ours = analyze_hlo(compiled.as_text()).flops
     dot_flops = 2 * 64 * 128 * 128
     assert raw < 2 * dot_flops  # XLA: body counted once
@@ -39,14 +37,70 @@ def test_hlo_cost_counts_scan_trip_counts():
     assert ours < 12 * dot_flops
 
 
+def test_init_process_x64_follows_platform_and_cache_rule(monkeypatch,
+                                                         tmp_path):
+    """x64 on only on the CPU; the compile cache at the checkout's fixed
+    .jax_cache/ unless JAX_COMPILATION_CACHE_DIR names one, in which case
+    nothing is configured."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro import runtime
+
+    x64, cache_dir = (jax.config.jax_enable_x64,
+                      jax.config.jax_compilation_cache_dir)
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert runtime.init_process() is True
+        assert jax.config.jax_enable_x64
+        assert runtime.CACHE_DIR == Path(__file__).resolve().parents[1] \
+            / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == str(runtime.CACHE_DIR)
+        assert runtime.init_process(x64=False) is False
+        assert not jax.config.jax_enable_x64
+
+        monkeypatch.setattr(runtime, "on_cpu", lambda: False)
+        assert runtime.init_process() is False  # an accelerator: never x64
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        runtime.init_process()
+        assert jax.config.jax_compilation_cache_dir is None
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        compilation_cache.reset_cache()
+
+
+def test_init_process_creates_no_backend_when_platform_named():
+    """With JAX_PLATFORMS=cpu, init_process decides x64 from the config
+    alone, so a later `import repro.linalg` still switches off XLA:CPU
+    async dispatch (it only takes effect before the CPU backend exists)."""
+    code = (
+        "import warnings; warnings.simplefilter('error')\n"
+        "import jax\n"
+        "from jax._src import xla_bridge as xb\n"
+        "from repro.runtime import init_process\n"
+        "assert init_process() is True\n"
+        "assert not xb._backends, 'init_process created a backend'\n"
+        "import repro.linalg\n"
+        "assert not xb._CPU_ENABLE_ASYNC_DISPATCH.value\n"
+        "assert jax.numpy.zeros(2).dtype == 'float64'\n"
+    )
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": str(ROOT / "src")}
+    env.pop("JAX_CPU_ENABLE_ASYNC_DISPATCH", None)
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 def test_hlo_cost_collectives_in_loops():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.launch.hlo_cost import analyze_hlo
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_smoke_mesh
 
-    mesh = make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+    mesh = make_smoke_mesh((2, 4))
     xs = jax.ShapeDtypeStruct((16, 64), jnp.float32,
                               sharding=NamedSharding(mesh, P("data", None)))
     ws = jax.ShapeDtypeStruct((64, 64), jnp.float32,

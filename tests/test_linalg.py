@@ -472,3 +472,16 @@ def test_session_cache_eviction():
     assert len(ctx._sessions) == 2
     ctx.clear()
     assert not ctx._sessions
+
+
+def test_host_thread_keeps_the_callers_default_device():
+    """pure_callback runs its body with the host CPU as default device; the
+    hop onto the linalg host thread must keep it (on a TPU the protocol
+    would otherwise queue on the chip behind the program waiting on it)."""
+    from repro.linalg.ops import _on_host_thread
+
+    placed = _on_host_thread(lambda: jnp.zeros(()).devices())
+    other = jax.devices()[-1]
+    with jax.default_device(other):
+        assert placed() == {other}
+    assert placed() == {jax.devices()[0]}

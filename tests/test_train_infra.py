@@ -124,12 +124,9 @@ def test_elastic_restore_onto_different_mesh():
         mgr = CheckpointManager(d)
         st = {"w": jnp.arange(16.0).reshape(4, 4)}
         mgr.save(1, st, blocking=True)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_smoke_mesh
 
-        mesh = make_mesh(
-            (4,), ("data",),
-            devices=jax.devices()[:4],
-        )
+        mesh = make_smoke_mesh((4,), ("data",))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         placed, _ = mgr.restore_sharded(st, sh)
         assert len(placed["w"].sharding.device_set) == 4
