@@ -52,6 +52,7 @@ from repro.core.lu import nserver_comm_model
 from repro.core.prt import rotate_degree
 from repro.core.seed import Seed, seedgen, seedgen_batch
 from repro.core.verify import authenticate
+from repro.spans import span
 
 from .messages import ShardResult, ShardTask
 from .transport import Transport, TransportConfig, resolve_transport
@@ -257,27 +258,28 @@ class SPDCClient:
         fused transports, worker-side for message transports); tamper is
         a client-side hook on the assembled factors.
         """
-        t0 = time.perf_counter()
-        plan = resolve_delays(
-            normalize_plan(faults),
-            # rateless has no rounds deadline — slow servers just do less
-            None if self.rateless is not None else self.straggler_deadline,
-        )
-        if isinstance(m, (list, tuple)):
-            sess = self._open_mixed(m, num_servers, plan, tamper, pad_to)
-        else:
-            if pad_to is not None:
-                raise ValueError("pad_to applies to mixed-size lists only")
-            m = jnp.asarray(m, dtype=self.dtype)
-            if m.ndim == 3:
-                sess = self._open_batch(m, num_servers, plan, tamper)
+        # the span ends once the ciphertext is on the device
+        with span("spdc.pmop", wait=lambda: sess.x_aug) as pmop:
+            plan = resolve_delays(
+                normalize_plan(faults),
+                # rateless has no rounds deadline — slow servers just do less
+                None if self.rateless is not None else self.straggler_deadline,
+            )
+            if isinstance(m, (list, tuple)):
+                sess = self._open_mixed(m, num_servers, plan, tamper, pad_to)
             else:
-                if m.ndim != 2 or m.shape[0] != m.shape[1]:
-                    raise ValueError(
-                        f"expected a square matrix, got {m.shape}"
-                    )
-                sess = self._open_single(m, num_servers, plan, tamper)
-        sess._pmop_s = time.perf_counter() - t0
+                if pad_to is not None:
+                    raise ValueError("pad_to applies to mixed-size lists only")
+                m = jnp.asarray(m, dtype=self.dtype)
+                if m.ndim == 3:
+                    sess = self._open_batch(m, num_servers, plan, tamper)
+                else:
+                    if m.ndim != 2 or m.shape[0] != m.shape[1]:
+                        raise ValueError(
+                            f"expected a square matrix, got {m.shape}"
+                        )
+                    sess = self._open_single(m, num_servers, plan, tamper)
+        sess._pmop_s = pmop.seconds
         return sess
 
     def _open_single(self, m, num_servers, plan, tamper) -> "Session":
@@ -448,8 +450,8 @@ class Session:
     _factors: tuple | None = None
     _m_host: np.ndarray | None = None
     _m_hosts: list[np.ndarray] = field(default_factory=list)
-    # phase timings feeding SPDCReport.timings (client.open_session stamps
-    # _pmop_s; run/start stamp _dispatch_s; collect adds its own)
+    # phase timings feeding SPDCReport.timings: the spdc.pmop and
+    # spdc.sweep spans' seconds (collect adds its own)
     _pmop_s: float = 0.0
     _dispatch_s: float = 0.0
 
@@ -614,25 +616,27 @@ class Session:
         """
         transport = self._resolve_transport(transport)
         self._style = transport.style
-        t0 = time.perf_counter()
-        if self.num_strips is not None:
-            from repro.distrib.rateless import run_rateless
+        # the span ends once the factors are on the device
+        with span("spdc.sweep", wait=lambda: (l, u)) as sweep:
+            if self.num_strips is not None:
+                from repro.distrib.rateless import run_rateless
 
-            self._style = "nserver"  # the scheduler's strip primitive
-            l_host, u_host, rpt = run_rateless(
-                self, transport, self.client.rateless, self.client.fleet,
-                faults=self.plan,
-            )
-            self.fleet_report = rpt
-            dt = self.x_aug.dtype
-            l, u = jnp.asarray(l_host, dtype=dt), jnp.asarray(u_host, dtype=dt)
-        elif transport.fused:
-            l, u = transport.sweep(self.x_aug, self.num_servers,
-                                   faults=self.plan)
-        else:
-            results = transport.factor(self.tasks(), faults=self.plan)
-            l, u = self._assemble(results)
-        self._dispatch_s = time.perf_counter() - t0
+                self._style = "nserver"  # the scheduler's strip primitive
+                l_host, u_host, rpt = run_rateless(
+                    self, transport, self.client.rateless, self.client.fleet,
+                    faults=self.plan,
+                )
+                self.fleet_report = rpt
+                dt = self.x_aug.dtype
+                l = jnp.asarray(l_host, dtype=dt)
+                u = jnp.asarray(u_host, dtype=dt)
+            elif transport.fused:
+                l, u = transport.sweep(self.x_aug, self.num_servers,
+                                       faults=self.plan)
+            else:
+                results = transport.factor(self.tasks(), faults=self.plan)
+                l, u = self._assemble(results)
+        self._dispatch_s = sweep.seconds
         return self.collect((l, u), transport=transport)
 
     def start(self, transport=None) -> "PendingResult":
@@ -643,50 +647,51 @@ class Session:
         threads (`Transport.driver_submit`), so the caller's NEXT
         `open_session` — the client PMOP for batch k+1 — overlaps this
         session's wire time; `SPDCClient.run_pipelined` is the loop
-        built on exactly this. Fused transports complete the future
-        synchronously — jax's own async dispatch already provides the
-        overlap there.
+        built on exactly this. There the `spdc.sweep` span opens and
+        closes on the driver thread, where the factors arrive. Fused
+        transports complete the future synchronously, once the factors
+        are on the device.
         """
         transport = self._resolve_transport(transport)
         self._style = transport.style
-        t0 = time.perf_counter()
         if self.num_strips is not None:
-            from concurrent.futures import Future as _Future  # noqa: F401
             from repro.distrib.rateless import run_rateless
 
             self._style = "nserver"
 
             def drive_rateless():
-                l_host, u_host, rpt = run_rateless(
-                    self, transport, self.client.rateless,
-                    self.client.fleet, faults=self.plan,
-                )
-                self.fleet_report = rpt
-                dt = self.x_aug.dtype
-                out = (jnp.asarray(l_host, dtype=dt),
-                       jnp.asarray(u_host, dtype=dt))
-                self._dispatch_s = time.perf_counter() - t0
+                with span("spdc.sweep", wait=lambda: out) as sweep:
+                    l_host, u_host, rpt = run_rateless(
+                        self, transport, self.client.rateless,
+                        self.client.fleet, faults=self.plan,
+                    )
+                    self.fleet_report = rpt
+                    dt = self.x_aug.dtype
+                    out = (jnp.asarray(l_host, dtype=dt),
+                           jnp.asarray(u_host, dtype=dt))
+                self._dispatch_s = sweep.seconds
                 return out
 
             future = transport.driver_submit(drive_rateless)
         elif transport.fused:
-            from concurrent.futures import Future as _Future
+            from concurrent.futures import Future
 
-            future = _Future()
+            future = Future()
             try:
-                future.set_result(
-                    transport.sweep(self.x_aug, self.num_servers,
-                                    faults=self.plan)
-                )
-                self._dispatch_s = time.perf_counter() - t0
+                with span("spdc.sweep", wait=lambda: out) as sweep:
+                    out = transport.sweep(self.x_aug, self.num_servers,
+                                          faults=self.plan)
+                self._dispatch_s = sweep.seconds
+                future.set_result(out)
             except Exception as e:  # noqa: BLE001 — future carries it
                 future.set_exception(e)
         else:
             tasks = self.tasks()  # boundary-checked on THIS thread
 
             def drive_factor():
-                out = transport.factor(tasks, self.plan)
-                self._dispatch_s = time.perf_counter() - t0
+                with span("spdc.sweep") as sweep:
+                    out = transport.factor(tasks, self.plan)
+                self._dispatch_s = sweep.seconds
                 return out
 
             future = transport.driver_submit(drive_factor)
@@ -736,42 +741,44 @@ class Session:
             l, u = self._assemble(results)
         if self.tamper is not None:
             l, u = self.tamper(l, u)
-        verdict = authenticate(
-            l, u, self.x_aug, num_servers=self.partitions,
-            method=self.client.method, rng=_probe_rng(self.digest),
-        )
-        report = None
-        if self.client.recover and not bool(np.all(verdict.ok)):
-            fleet = self.client.fleet
+        fleet = self.client.fleet
 
-            def dispatch(x, u_now, server, attempt, replacement):
-                # recovery IS re-streaming one strip: rateless sessions
-                # route the re-issue to the healthiest live worker (or
-                # compute it inline when the fleet is gone) instead of
-                # the pool's positional replacement
-                task = self._repair_task(server, attempt, u_now)
-                if fleet is not None:
-                    ids = tuple(range(self.num_servers))
-                    live = (fleet.assignable(ids, set(), time.monotonic())
-                            or fleet.live(ids))
-                    if live:
-                        res = transport.repair(task, replacement=live[0])
-                    else:
-                        from .server import EdgeServer
-
-                        res = EdgeServer(None).run(task)
+        def dispatch(x, u_now, server, attempt, replacement):
+            # recovery IS re-streaming one strip: rateless sessions
+            # route the re-issue to the healthiest live worker (or
+            # compute it inline when the fleet is gone) instead of
+            # the pool's positional replacement
+            task = self._repair_task(server, attempt, u_now)
+            if fleet is not None:
+                ids = tuple(range(self.num_servers))
+                live = (fleet.assignable(ids, set(), time.monotonic())
+                        or fleet.live(ids))
+                if live:
+                    res = transport.repair(task, replacement=live[0])
                 else:
-                    res = transport.repair(task, replacement=replacement)
-                dt = self.x_aug.dtype
-                return (jnp.asarray(res.l_row, dtype=dt),
-                        jnp.asarray(res.u_row, dtype=dt))
+                    from .server import EdgeServer
 
-            l, u, verdict, report = recover_lu(
+                    res = EdgeServer(None).run(task)
+            else:
+                res = transport.repair(task, replacement=replacement)
+            dt = self.x_aug.dtype
+            return (jnp.asarray(res.l_row, dtype=dt),
+                    jnp.asarray(res.u_row, dtype=dt))
+
+        # the verdict arrives as host scalars / numpy arrays: no wait
+        with span("spdc.verify"):
+            verdict = authenticate(
                 l, u, self.x_aug, num_servers=self.partitions,
-                method=self.client.method, standby=self.client.standby,
-                digest=self.digest, style=self._style, verdict=verdict,
-                dispatch=dispatch,
+                method=self.client.method, rng=_probe_rng(self.digest),
             )
+            report = None
+            if self.client.recover and not bool(np.all(verdict.ok)):
+                l, u, verdict, report = recover_lu(
+                    l, u, self.x_aug, num_servers=self.partitions,
+                    method=self.client.method, standby=self.client.standby,
+                    digest=self.digest, style=self._style, verdict=verdict,
+                    dispatch=dispatch,
+                )
         if self.keep_factors:
             # post-recovery: these are the factors Authenticate accepted,
             # so every later trisolve round goes through healed material
@@ -795,10 +802,12 @@ class Session:
                 ),
             )
 
+        # Decipher sums the factor diagonals on the host: no wait
         if self.kind == "single":
-            det = decipher(self.seeds[0], self.metas[0], l, u,
-                           faithful=self.client.faithful_sign,
-                           log2_scale=self.log2_scale)
+            with span("spdc.decipher"):
+                det = decipher(self.seeds[0], self.metas[0], l, u,
+                               faithful=self.client.faithful_sign,
+                               log2_scale=self.log2_scale)
             return SPDCResult(
                 det=det,
                 verified=bool(np.all(verdict.ok)),
@@ -810,9 +819,10 @@ class Session:
                 num_servers=self.num_servers,
                 report=build_report(),
             )
-        dets = decipher_batch(self.seeds, self.metas, l, u,
-                              faithful=self.client.faithful_sign,
-                              log2_scale=np.asarray(self.log2_scale))
+        with span("spdc.decipher"):
+            dets = decipher_batch(self.seeds, self.metas, l, u,
+                                  faithful=self.client.faithful_sign,
+                                  log2_scale=np.asarray(self.log2_scale))
         return SPDCBatchResult(
             dets=dets,
             verified=np.atleast_1d(np.asarray(verdict.ok)),

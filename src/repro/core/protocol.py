@@ -80,9 +80,13 @@ class SessionTimings:
     """Wall-clock phase breakdown of one protocol run (seconds).
 
     pmop_s is the client-side prepare (seed/key/cipher/equilibrate/
-    border); dispatch_s is the Parallelize stage as the client saw it —
-    for message transports, dominated by wire time; collect_s is the
-    RRVP tail (authenticate → recovery → decipher). With the
+    border) up to the ciphertext on the device; dispatch_s is the
+    Parallelize stage as the client saw it, up to the factors on the
+    device — for message transports, dominated by wire time. They are
+    the seconds of the `spdc.pmop` and `spdc.sweep` spans (repro.spans,
+    DESIGN.md §10.5). collect_s is the RRVP tail (authenticate → recovery
+    → decipher: the `spdc.verify` and `spdc.decipher` spans and the
+    bookkeeping around them). With the
     async-overlap API (`Session.start` / `SPDCClient.run_pipelined`,
     DESIGN.md §9) batch k+1's pmop_s runs INSIDE batch k's dispatch_s —
     the sum of phases across a pipelined run exceeds its wall clock,

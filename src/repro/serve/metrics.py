@@ -138,7 +138,10 @@ class FlushEvent:
     batch: int  # real requests in the sweep
     padded_batch: int  # batch after pad_batches dummies
     queue_waits_s: tuple[float, ...]  # per-request submit→flush wait
-    sweep_s: float  # device sweep wall time (virtual-clock delta in tests)
+    # the protocol call's wall time — PMOP + sweep + verify + decipher, not
+    # the device sweep alone — on the gateway's clock (virtual in tests);
+    # the name is pinned by /metrics schema v1
+    sweep_s: float
     recovered: bool = False
     error: str | None = None
 

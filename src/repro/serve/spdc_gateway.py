@@ -64,6 +64,7 @@ import numpy as np
 from repro.api.transport import Transport, TransportConfig
 from repro.configs.spdc import SPDC_GATEWAY_DEFAULT, SPDCGatewayConfig
 from repro.core.protocol import outsource_determinant_mixed, resolve_dtype
+from repro.spans import span
 
 from .locking import assert_owns_lock
 from .metrics import (
@@ -447,164 +448,167 @@ class SPDCGateway:
         a compiled sweep with f64 clients, and an inline sweep never
         coalesces with a multiprocess one.
         """
-        unknown = set(overrides) - _OVERRIDE_KEYS
-        if unknown:
-            # a misspelled security override must fail loudly — silently
-            # serving under the gateway defaults would hand the client a
-            # weaker config than it asked for
-            raise TypeError(
-                f"unknown submit() overrides {sorted(unknown)}; "
-                f"allowed: {sorted(_OVERRIDE_KEYS)}"
-            )
-        if op not in _OPS:
-            raise ValueError(f"unknown op {op!r}; expected one of {_OPS}")
-        matrix = np.asarray(matrix)
-        if matrix.ndim != 2 or matrix.shape[0] != matrix.shape[1]:
-            raise ValueError(f"expected one square matrix, got {matrix.shape}")
-        n = int(matrix.shape[0])
-        if n < 2:
-            raise ValueError("matrices must be at least 2x2 (KeyGen needs "
-                             "n >= 2 blinding elements)")
-        if not np.all(np.isfinite(matrix)):
-            raise ValueError("matrix contains non-finite entries")
-        if op == "solve":
-            if rhs is None:
-                raise ValueError('op="solve" needs an rhs')
-            rhs = np.asarray(rhs)
-            if rhs.ndim not in (1, 2) or rhs.shape[0] != n:
-                raise ValueError(
-                    f"rhs shape {rhs.shape} does not match matrix "
-                    f"({n}, {n})"
+        # admission, cache lookup and enqueue; a direct call or an auto
+        # flush runs after the span, under spans of its own
+        with span("spdc.gateway.submit"):
+            unknown = set(overrides) - _OVERRIDE_KEYS
+            if unknown:
+                # a misspelled security override must fail loudly — silently
+                # serving under the gateway defaults would hand the client a
+                # weaker config than it asked for
+                raise TypeError(
+                    f"unknown submit() overrides {sorted(unknown)}; "
+                    f"allowed: {sorted(_OVERRIDE_KEYS)}"
                 )
-            if not np.all(np.isfinite(rhs)):
-                raise ValueError("rhs contains non-finite entries")
-        elif rhs is not None:
-            raise ValueError(f'op={op!r} takes no rhs')
-        now = self._clock() if now is None else now
-        hook_events = []
-        try:
-            with self._lock:
-                try:
-                    key = self._key_for(n, overrides, op)
-                except NoBucketFits:
-                    key = None
-                self.metrics.record_submit(tenant)
-                # 1. admission: the tenant's token bucket guards the door
-                # for EVERY request shape (bucketed, direct, cache hit)
-                try:
-                    self._admission.charge(tenant, now)
-                except AdmissionRejected:
-                    self.stats.rejected_admission += 1
-                    hook_events.append(
-                        ("reject", self._reject("rate", tenant, key)))
-                    raise
-                rid = self._next_rid
-                self._next_rid += 1
-                self.stats.submitted += 1
-                breaker = None
-                probe_granted = False
-                req = DetRequest(rid=rid, matrix=matrix, n=n,
-                                 enqueued_at=now, tenant=tenant,
-                                 op=op, rhs=rhs)
-                if key is not None:
-                    # 2. idempotency cache / single-flight (cache hits cost
-                    # O(hash) — they bypass breaker and quota entirely)
-                    if self._cache is not None:
-                        req.ckey = self._cache_key(key, tenant, matrix, rhs)
-                        hit = self._cache.get(req.ckey)
-                        if hit is not None:
-                            self.stats.cache_hits += 1
-                            self.metrics.counters["cache_hits"] += 1
-                            gres = replace(
-                                hit, rid=rid, submitted_at=now,
-                                completed_at=now, flush_reason="cache",
-                                batch=1, recovery=None, cache_hit=True,
-                                tenant=tenant,
-                            )
-                            self.metrics.counters["admitted"] += 1
-                            hook_events.append(("verdict", self._deliver(
-                                gres, key.label())))
-                            return rid
-                        self.stats.cache_misses += 1
-                        self.metrics.counters["cache_misses"] += 1
-                        if self.config.cache.single_flight:
-                            entry = self._inflight.get(req.ckey)
-                            if entry is not None:
-                                # ride the leader's sweep; quota still holds
-                                # a slot (the follower occupies memory and a
-                                # waiter until delivery)
-                                try:
-                                    self._admission.acquire_slot(tenant)
-                                except AdmissionRejected:
-                                    self.stats.submitted -= 1
-                                    self.stats.rejected_admission += 1
-                                    hook_events.append(
-                                        ("reject",
-                                         self._reject("quota", tenant, key)))
-                                    raise
-                                entry.followers.append(req)
-                                self.stats.coalesced += 1
-                                self.metrics.counters["coalesced"] += 1
-                                self.metrics.counters["admitted"] += 1
-                                return rid
-                    # 3. circuit breaker: a poisoned bucket fast-fails or
-                    # detours instead of poisoning a shared sweep
-                    breaker = self._breaker_for(key)
-                    verdict = breaker.allow(now)
-                    if verdict == "open":
-                        if self.config.breaker.on_open == "direct":
-                            self.stats.degraded_direct += 1
-                            key = None  # detour: served, but un-coalesced
-                        else:
-                            self.stats.submitted -= 1
-                            self.stats.rejected_breaker += 1
-                            hook_events.append(
-                                ("reject",
-                                 self._reject("breaker", tenant, key)))
-                            raise BreakerOpen(
-                                f"bucket {key.label()} is fast-failing "
-                                "after repeated sweep failures; retry in "
-                                f"{breaker.retry_after(now):.3f}s",
-                                bucket=key.label(),
-                                retry_after_s=breaker.retry_after(now),
-                            )
-                    elif verdict == "probe":
-                        probe_granted = True
-                        self.stats.breaker_probes += 1
-                        self.metrics.counters["breaker_probes"] += 1
-                if key is not None:
-                    # 4. per-tenant pending quota, then the gateway-wide
-                    # capacity door; BOTH unwind completely on rejection —
-                    # including a just-granted half-open probe, which must
-                    # return to "open" (with next_probe_at already in the
-                    # past) or no flush would ever record() and the bucket
-                    # would fast-fail forever
+            if op not in _OPS:
+                raise ValueError(f"unknown op {op!r}; expected one of {_OPS}")
+            matrix = np.asarray(matrix)
+            if matrix.ndim != 2 or matrix.shape[0] != matrix.shape[1]:
+                raise ValueError(f"expected one square matrix, got {matrix.shape}")
+            n = int(matrix.shape[0])
+            if n < 2:
+                raise ValueError("matrices must be at least 2x2 (KeyGen needs "
+                                 "n >= 2 blinding elements)")
+            if not np.all(np.isfinite(matrix)):
+                raise ValueError("matrix contains non-finite entries")
+            if op == "solve":
+                if rhs is None:
+                    raise ValueError('op="solve" needs an rhs')
+                rhs = np.asarray(rhs)
+                if rhs.ndim not in (1, 2) or rhs.shape[0] != n:
+                    raise ValueError(
+                        f"rhs shape {rhs.shape} does not match matrix "
+                        f"({n}, {n})"
+                    )
+                if not np.all(np.isfinite(rhs)):
+                    raise ValueError("rhs contains non-finite entries")
+            elif rhs is not None:
+                raise ValueError(f'op={op!r} takes no rhs')
+            now = self._clock() if now is None else now
+            hook_events = []
+            try:
+                with self._lock:
                     try:
-                        self._admission.acquire_slot(tenant)
+                        key = self._key_for(n, overrides, op)
+                    except NoBucketFits:
+                        key = None
+                    self.metrics.record_submit(tenant)
+                    # 1. admission: the tenant's token bucket guards the door
+                    # for EVERY request shape (bucketed, direct, cache hit)
+                    try:
+                        self._admission.charge(tenant, now)
                     except AdmissionRejected:
-                        if probe_granted:
-                            breaker.revert_probe()
-                        self.stats.submitted -= 1
                         self.stats.rejected_admission += 1
                         hook_events.append(
-                            ("reject", self._reject("quota", tenant, key)))
+                            ("reject", self._reject("rate", tenant, key)))
                         raise
-                    try:
-                        full = self._queue.push(key, req)
-                    except GatewayOverloaded:
-                        if probe_granted:
-                            breaker.revert_probe()
-                        self._admission.release_slot(tenant)
-                        self.stats.submitted -= 1
-                        self.stats.rejected += 1
-                        hook_events.append(
-                            ("reject", self._reject("overload", tenant, key)))
-                        raise
-                    if req.ckey is not None and self.config.cache.single_flight:
-                        self._inflight[req.ckey] = _InFlight(rid)
-                self.metrics.counters["admitted"] += 1
-        finally:
-            self._fire(hook_events)
+                    rid = self._next_rid
+                    self._next_rid += 1
+                    self.stats.submitted += 1
+                    breaker = None
+                    probe_granted = False
+                    req = DetRequest(rid=rid, matrix=matrix, n=n,
+                                     enqueued_at=now, tenant=tenant,
+                                     op=op, rhs=rhs)
+                    if key is not None:
+                        # 2. idempotency cache / single-flight (cache hits cost
+                        # O(hash) — they bypass breaker and quota entirely)
+                        if self._cache is not None:
+                            req.ckey = self._cache_key(key, tenant, matrix, rhs)
+                            hit = self._cache.get(req.ckey)
+                            if hit is not None:
+                                self.stats.cache_hits += 1
+                                self.metrics.counters["cache_hits"] += 1
+                                gres = replace(
+                                    hit, rid=rid, submitted_at=now,
+                                    completed_at=now, flush_reason="cache",
+                                    batch=1, recovery=None, cache_hit=True,
+                                    tenant=tenant,
+                                )
+                                self.metrics.counters["admitted"] += 1
+                                hook_events.append(("verdict", self._deliver(
+                                    gres, key.label())))
+                                return rid
+                            self.stats.cache_misses += 1
+                            self.metrics.counters["cache_misses"] += 1
+                            if self.config.cache.single_flight:
+                                entry = self._inflight.get(req.ckey)
+                                if entry is not None:
+                                    # ride the leader's sweep; quota still holds
+                                    # a slot (the follower occupies memory and a
+                                    # waiter until delivery)
+                                    try:
+                                        self._admission.acquire_slot(tenant)
+                                    except AdmissionRejected:
+                                        self.stats.submitted -= 1
+                                        self.stats.rejected_admission += 1
+                                        hook_events.append(
+                                            ("reject",
+                                             self._reject("quota", tenant, key)))
+                                        raise
+                                    entry.followers.append(req)
+                                    self.stats.coalesced += 1
+                                    self.metrics.counters["coalesced"] += 1
+                                    self.metrics.counters["admitted"] += 1
+                                    return rid
+                        # 3. circuit breaker: a poisoned bucket fast-fails or
+                        # detours instead of poisoning a shared sweep
+                        breaker = self._breaker_for(key)
+                        verdict = breaker.allow(now)
+                        if verdict == "open":
+                            if self.config.breaker.on_open == "direct":
+                                self.stats.degraded_direct += 1
+                                key = None  # detour: served, but un-coalesced
+                            else:
+                                self.stats.submitted -= 1
+                                self.stats.rejected_breaker += 1
+                                hook_events.append(
+                                    ("reject",
+                                     self._reject("breaker", tenant, key)))
+                                raise BreakerOpen(
+                                    f"bucket {key.label()} is fast-failing "
+                                    "after repeated sweep failures; retry in "
+                                    f"{breaker.retry_after(now):.3f}s",
+                                    bucket=key.label(),
+                                    retry_after_s=breaker.retry_after(now),
+                                )
+                        elif verdict == "probe":
+                            probe_granted = True
+                            self.stats.breaker_probes += 1
+                            self.metrics.counters["breaker_probes"] += 1
+                    if key is not None:
+                        # 4. per-tenant pending quota, then the gateway-wide
+                        # capacity door; BOTH unwind completely on rejection —
+                        # including a just-granted half-open probe, which must
+                        # return to "open" (with next_probe_at already in the
+                        # past) or no flush would ever record() and the bucket
+                        # would fast-fail forever
+                        try:
+                            self._admission.acquire_slot(tenant)
+                        except AdmissionRejected:
+                            if probe_granted:
+                                breaker.revert_probe()
+                            self.stats.submitted -= 1
+                            self.stats.rejected_admission += 1
+                            hook_events.append(
+                                ("reject", self._reject("quota", tenant, key)))
+                            raise
+                        try:
+                            full = self._queue.push(key, req)
+                        except GatewayOverloaded:
+                            if probe_granted:
+                                breaker.revert_probe()
+                            self._admission.release_slot(tenant)
+                            self.stats.submitted -= 1
+                            self.stats.rejected += 1
+                            hook_events.append(
+                                ("reject", self._reject("overload", tenant, key)))
+                            raise
+                        if req.ckey is not None and self.config.cache.single_flight:
+                            self._inflight[req.ckey] = _InFlight(rid)
+                    self.metrics.counters["admitted"] += 1
+            finally:
+                self._fire(hook_events)
         if key is None:
             self._run_direct(req, overrides, now)
         elif full and self._auto_flush:
@@ -698,34 +702,42 @@ class SPDCGateway:
         return entry.followers
 
     def _flush(self, key: BucketKey, reason: str, now: float):
-        with self._lock:
-            reqs = self._queue.pop(key, limit=self.config.max_batch)
-            if not reqs:
-                return []
-            self.stats.flushes += 1
-            if reason == "full":
-                self.stats.flushes_full += 1
-            elif reason == "timeout":
-                self.stats.flushes_timeout += 1
-            else:
-                self.stats.flushes_drain += 1
+        # spans: pack (pop + padding), the protocol's own phases, deliver
+        with span("spdc.gateway.pack"):
+            with self._lock:
+                reqs = self._queue.pop(key, limit=self.config.max_batch)
+                if not reqs:
+                    return []
+                self.stats.flushes += 1
+                if reason == "full":
+                    self.stats.flushes_full += 1
+                elif reason == "timeout":
+                    self.stats.flushes_timeout += 1
+                else:
+                    self.stats.flushes_drain += 1
+            mats = [r.matrix for r in reqs]
+            if key.op != "solve" and self.config.pad_batches:
+                # the requests are already popped from the queue, so a
+                # padding failure must fail THEM, not vanish them and
+                # hang their waiters
+                try:
+                    target = next(
+                        b for b in allowed_batch_sizes(self.config.max_batch)
+                        if b >= len(mats)
+                    )
+                    mats = mats + [
+                        self._dummy(key.pad_to, key.dtype)
+                        for _ in range(target - len(mats))
+                    ]
+                except Exception as e:  # noqa: BLE001 — fail them, not the service
+                    return self._fail_requests(
+                        reqs, key, reason, f"{type(e).__name__}: {e}",
+                        flush_now=now, padded_batch=len(mats),
+                    )
         if key.op == "solve":
             return self._flush_solve(key, reqs, reason, now)
-        mats = [r.matrix for r in reqs]
         sweep_t0 = self._clock()
         try:
-            # padding runs inside the try: the requests are already popped
-            # from the queue, so a padding failure must fail THEM (below),
-            # not vanish them and hang their waiters
-            if self.config.pad_batches:
-                target = next(
-                    b for b in allowed_batch_sizes(self.config.max_batch)
-                    if b >= len(mats)
-                )
-                mats = mats + [
-                    self._dummy(key.pad_to, key.dtype)
-                    for _ in range(target - len(mats))
-                ]
             faults = self._faults_for(key) if self._faults_for else None
             res = outsource_determinant_mixed(
                 mats,
@@ -737,75 +749,77 @@ class SPDCGateway:
             # the bucket is already popped: every co-batched request gets
             # its own failed result instead of vanishing (and the async
             # flusher keeps running)
-            return self._fail_requests(
-                reqs, key, reason, f"{type(e).__name__}: {e}",
-                flush_now=now, sweep_t0=sweep_t0, padded_batch=len(mats),
-            )
-        done = self._clock()
-        label = key.label()
-        out = []
-        hook_events = []
-        with self._lock:
-            if res.report.recovery is not None:
-                self.stats.recovered_flushes += 1
-            n_verified = sum(
-                1 for i in range(len(reqs)) if bool(res.verified[i])
-            )
-            unverified_rate = 1.0 - n_verified / len(reqs)
-            self._record_breaker(key, now=done, failed=False,
-                                 unverified_rate=unverified_rate)
-            flush_ev = FlushEvent(
-                bucket=label, reason=reason, batch=len(reqs),
-                padded_batch=len(mats),
-                queue_waits_s=tuple(now - r.enqueued_at for r in reqs),
-                sweep_s=done - sweep_t0,
-                recovered=res.report.recovery is not None,
-            )
-            self.metrics.record_flush(flush_ev)
-            hook_events.append(("flush", flush_ev))
-            for i, req in enumerate(reqs):
-                det = res.dets[i]
-                gres = GatewayResult(
-                    rid=req.rid,
-                    det=det,
-                    verified=bool(res.verified[i]),
-                    residual=float(res.residual[i]),
-                    n=req.n,
-                    pad_to=key.pad_to,
-                    batch=len(reqs),
-                    flush_reason=reason,
-                    submitted_at=req.enqueued_at,
-                    completed_at=done,
-                    recovery=res.report.recovery,
-                    tenant=req.tenant,
-                    op=key.op,
-                    # slogdet answers in the overflow-safe pair the client
-                    # asked for; .value would overflow exactly where the
-                    # protocol's log-space arithmetic was built to survive
-                    sign=float(det.sign) if key.op == "slogdet" else None,
-                    logabs=float(det.logabs) if key.op == "slogdet" else None,
+            with span("spdc.gateway.deliver"):
+                return self._fail_requests(
+                    reqs, key, reason, f"{type(e).__name__}: {e}",
+                    flush_now=now, sweep_t0=sweep_t0, padded_batch=len(mats),
                 )
-                hook_events.append(("verdict", self._deliver(gres, label)))
-                out.append(gres)
-                self.stats.served += 1
-                self._admission.release_slot(req.tenant)
-                # cache-aside: ONLY verified results (a rejected verdict
-                # must not outlive its sweep), stored before followers so
-                # late identical submissions hit instead of re-leading
-                if (req.ckey is not None and self._cache is not None
-                        and gres.verified and gres.error is None):
-                    self._cache.put(req.ckey, gres)
-                for f in self._followers_of(req):
-                    fres = replace(
-                        gres, rid=f.rid, submitted_at=f.enqueued_at,
-                        flush_reason="coalesced", tenant=f.tenant,
+        with span("spdc.gateway.deliver"):
+            done = self._clock()
+            label = key.label()
+            out = []
+            hook_events = []
+            with self._lock:
+                if res.report.recovery is not None:
+                    self.stats.recovered_flushes += 1
+                n_verified = sum(
+                    1 for i in range(len(reqs)) if bool(res.verified[i])
+                )
+                unverified_rate = 1.0 - n_verified / len(reqs)
+                self._record_breaker(key, now=done, failed=False,
+                                     unverified_rate=unverified_rate)
+                flush_ev = FlushEvent(
+                    bucket=label, reason=reason, batch=len(reqs),
+                    padded_batch=len(mats),
+                    queue_waits_s=tuple(now - r.enqueued_at for r in reqs),
+                    sweep_s=done - sweep_t0,
+                    recovered=res.report.recovery is not None,
+                )
+                self.metrics.record_flush(flush_ev)
+                hook_events.append(("flush", flush_ev))
+                for i, req in enumerate(reqs):
+                    det = res.dets[i]
+                    gres = GatewayResult(
+                        rid=req.rid,
+                        det=det,
+                        verified=bool(res.verified[i]),
+                        residual=float(res.residual[i]),
+                        n=req.n,
+                        pad_to=key.pad_to,
+                        batch=len(reqs),
+                        flush_reason=reason,
+                        submitted_at=req.enqueued_at,
+                        completed_at=done,
+                        recovery=res.report.recovery,
+                        tenant=req.tenant,
+                        op=key.op,
+                        # slogdet answers in the overflow-safe pair the client
+                        # asked for; .value would overflow exactly where the
+                        # protocol's log-space arithmetic was built to survive
+                        sign=float(det.sign) if key.op == "slogdet" else None,
+                        logabs=float(det.logabs) if key.op == "slogdet" else None,
                     )
-                    hook_events.append(("verdict", self._deliver(fres, label)))
-                    out.append(fres)
+                    hook_events.append(("verdict", self._deliver(gres, label)))
+                    out.append(gres)
                     self.stats.served += 1
-                    self._admission.release_slot(f.tenant)
-        self._fire(hook_events)
-        return out
+                    self._admission.release_slot(req.tenant)
+                    # cache-aside: ONLY verified results (a rejected verdict
+                    # must not outlive its sweep), stored before followers so
+                    # late identical submissions hit instead of re-leading
+                    if (req.ckey is not None and self._cache is not None
+                            and gres.verified and gres.error is None):
+                        self._cache.put(req.ckey, gres)
+                    for f in self._followers_of(req):
+                        fres = replace(
+                            gres, rid=f.rid, submitted_at=f.enqueued_at,
+                            flush_reason="coalesced", tenant=f.tenant,
+                        )
+                        hook_events.append(("verdict", self._deliver(fres, label)))
+                        out.append(fres)
+                        self.stats.served += 1
+                        self._admission.release_slot(f.tenant)
+            self._fire(hook_events)
+            return out
 
     def _flush_solve(self, key: BucketKey, reqs, reason: str, now: float):
         """op="solve" flush engine: one verified LinalgSession per request.
@@ -840,70 +854,71 @@ class SPDCGateway:
                     (req, None, float("nan"), None,
                      f"{type(e).__name__}: {e}")
                 )
-        done = self._clock()
-        label = key.label()
-        out = []
-        hook_events = []
-        with self._lock:
-            n_failed = sum(1 for o in outcomes if o[4] is not None)
-            if any(o[3] is not None for o in outcomes):
-                self.stats.recovered_flushes += 1
-            self._record_breaker(
-                key, now=done, failed=n_failed == len(reqs),
-                unverified_rate=n_failed / len(reqs),
-            )
-            flush_ev = FlushEvent(
-                bucket=label, reason=reason, batch=len(reqs),
-                padded_batch=len(reqs),
-                queue_waits_s=tuple(now - r.enqueued_at for r in reqs),
-                sweep_s=done - sweep_t0,
-                recovered=any(o[3] is not None for o in outcomes),
-            )
-            self.metrics.record_flush(flush_ev)
-            hook_events.append(("flush", flush_ev))
-            for req, y, residual, recovery, error in outcomes:
-                ok = error is None
-                gres = GatewayResult(
-                    rid=req.rid,
-                    det=None,
-                    verified=ok,
-                    residual=residual,
-                    n=req.n,
-                    pad_to=key.pad_to,
-                    batch=len(reqs),
-                    flush_reason=reason,
-                    submitted_at=req.enqueued_at,
-                    completed_at=done,
-                    recovery=recovery,
-                    error=error,
-                    tenant=req.tenant,
-                    op="solve",
-                    solution=y,
+        with span("spdc.gateway.deliver"):
+            done = self._clock()
+            label = key.label()
+            out = []
+            hook_events = []
+            with self._lock:
+                n_failed = sum(1 for o in outcomes if o[4] is not None)
+                if any(o[3] is not None for o in outcomes):
+                    self.stats.recovered_flushes += 1
+                self._record_breaker(
+                    key, now=done, failed=n_failed == len(reqs),
+                    unverified_rate=n_failed / len(reqs),
                 )
-                hook_events.append(("verdict", self._deliver(gres, label)))
-                out.append(gres)
-                if ok:
-                    self.stats.served += 1
-                else:
-                    self.stats.failed += 1
-                self._admission.release_slot(req.tenant)
-                if (req.ckey is not None and self._cache is not None
-                        and ok):
-                    self._cache.put(req.ckey, gres)
-                for f in self._followers_of(req):
-                    fres = replace(
-                        gres, rid=f.rid, submitted_at=f.enqueued_at,
-                        flush_reason="coalesced", tenant=f.tenant,
+                flush_ev = FlushEvent(
+                    bucket=label, reason=reason, batch=len(reqs),
+                    padded_batch=len(reqs),
+                    queue_waits_s=tuple(now - r.enqueued_at for r in reqs),
+                    sweep_s=done - sweep_t0,
+                    recovered=any(o[3] is not None for o in outcomes),
+                )
+                self.metrics.record_flush(flush_ev)
+                hook_events.append(("flush", flush_ev))
+                for req, y, residual, recovery, error in outcomes:
+                    ok = error is None
+                    gres = GatewayResult(
+                        rid=req.rid,
+                        det=None,
+                        verified=ok,
+                        residual=residual,
+                        n=req.n,
+                        pad_to=key.pad_to,
+                        batch=len(reqs),
+                        flush_reason=reason,
+                        submitted_at=req.enqueued_at,
+                        completed_at=done,
+                        recovery=recovery,
+                        error=error,
+                        tenant=req.tenant,
+                        op="solve",
+                        solution=y,
                     )
-                    hook_events.append(("verdict", self._deliver(fres, label)))
-                    out.append(fres)
+                    hook_events.append(("verdict", self._deliver(gres, label)))
+                    out.append(gres)
                     if ok:
                         self.stats.served += 1
                     else:
                         self.stats.failed += 1
-                    self._admission.release_slot(f.tenant)
-        self._fire(hook_events)
-        return out
+                    self._admission.release_slot(req.tenant)
+                    if (req.ckey is not None and self._cache is not None
+                            and ok):
+                        self._cache.put(req.ckey, gres)
+                    for f in self._followers_of(req):
+                        fres = replace(
+                            gres, rid=f.rid, submitted_at=f.enqueued_at,
+                            flush_reason="coalesced", tenant=f.tenant,
+                        )
+                        hook_events.append(("verdict", self._deliver(fres, label)))
+                        out.append(fres)
+                        if ok:
+                            self.stats.served += 1
+                        else:
+                            self.stats.failed += 1
+                        self._admission.release_slot(f.tenant)
+            self._fire(hook_events)
+            return out
 
     #: requires-lock: self._lock
     def _record_breaker(self, key: BucketKey, *, now: float, failed: bool,
