@@ -19,26 +19,17 @@ not being the culprit, so repair tasks always execute honestly.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from jax.scipy.linalg import solve_triangular
 
 from repro.core.faults import corrupt_strip, normalize_plan, sample_delay
-from repro.core.lu import lu_block_row
+from repro.distrib.recovery import lu_block_row_jit
 
 from .messages import ShardResult, ShardTask, TriSolveResult, TriSolveTask
 
 __all__ = ["EdgeServer"]
-
-#: jitted strip recompute for (B, n, n) stacks — host dispatch would
-#: dominate otherwise; single matrices stay eager so the arithmetic
-#: bit-matches the eager lu_nserver simulation (core.lu.lu_block_row).
-_block_row_batched = jax.jit(
-    lu_block_row, static_argnums=(2, 3), static_argnames=("style",)
-)
-
 
 def _embed_rows(zeros, strip, row0, rows):
     """Place a (…, rows, n) strip into a zero (…, n, n) frame (eager —
@@ -96,9 +87,8 @@ class EdgeServer:
                 )
             u = zeros
         self._straggle(task, faults)
-        row_fn = _block_row_batched if x.ndim == 3 else lu_block_row
-        l_row, u_row = row_fn(x, u, task.server, task.num_servers,
-                              style=task.style)
+        l_row, u_row = lu_block_row_jit(x, u, task.server, task.num_servers,
+                                        style=task.style)
         l_row, u_row = self._misbehave(task, l_row, u_row, faults)
         return ShardResult(
             server=task.server,

@@ -150,9 +150,9 @@ def refuse_spawn_on_accelerator(transport: str) -> None:
 
 @partial(jax.jit, static_argnames=("num_servers", "faults"))
 def _lu_sweep(x_aug, *, num_servers, faults=()):
-    """Jitted fused sweep for (B, n', n') stacks — ONE device program per
-    (shape, N, fault-plan), the throughput lever the inline transport
-    exists to keep (DESIGN.md §3)."""
+    """Jitted fused sweep for one (n', n') matrix or a (B, n', n') stack —
+    ONE device program per (shape, N, fault-plan), whatever the rank,
+    traced once and then a cached dispatch (DESIGN.md §3)."""
     l, u, _ = lu_nserver(x_aug, num_servers, faults=faults)
     return l, u
 
@@ -341,11 +341,11 @@ class Transport:
 class InlineTransport(Transport):
     """Degenerate (single-process) transport: today's jitted fast path.
 
-    `sweep()` IS the pre-split protocol's server stage — eager lu_nserver
-    for one matrix (bit-matching the recovery recompute), one jitted
-    program for a stack — so results are bit-identical to the monolithic
-    `outsource_determinant` this API replaced. The message methods exist
-    for uniformity (tests drive them); the Session prefers `sweep()`.
+    `sweep()` IS the pre-split protocol's server stage — one jitted
+    program per (shape, N, fault plan) for a single matrix and a stack
+    alike, whose strips the jitted recovery recompute
+    (`distrib.recovery.lu_block_row_jit`) bit-matches. The message methods
+    exist for uniformity (tests drive them); the Session prefers `sweep()`.
     """
 
     name = "inline"
@@ -353,9 +353,6 @@ class InlineTransport(Transport):
 
     def sweep(self, x_aug, num_servers: int, faults=()):
         self._ensure_open()
-        if x_aug.ndim == 2:
-            l, u, _ = lu_nserver(x_aug, num_servers, faults=faults)
-            return l, u
         return _lu_sweep(x_aug, num_servers=num_servers, faults=faults)
 
     def factor(self, tasks, faults=()):

@@ -45,10 +45,10 @@ from repro.core.augment import augment_block_row
 from repro.core.lu import lu_block_row
 from repro.core.verify import Verdict, authenticate
 
-#: jitted recompute for (B, n, n) stacks, where host-side dispatch would
-#: dominate; single matrices stay un-jitted so the recompute's operation
-#: order matches the (un-jitted) lu_nserver run bit-for-bit.
-_block_row_batched = jax.jit(
+#: the jitted strip recompute, one program per (shape, server, N, style)
+#: for a single matrix and a stack alike: jitted like the inline sweep
+#: (api.transport._lu_sweep), so its strips match the sweep's bit for bit.
+lu_block_row_jit = jax.jit(
     lu_block_row, static_argnums=(2, 3), static_argnames=("style",)
 )
 
@@ -314,8 +314,8 @@ def recover_lu(
             if dispatch is not None:
                 l_row, u_row = dispatch(x, u, s, attempts[s], phys)
             else:
-                row_fn = _block_row_batched if batched else lu_block_row
-                l_row, u_row = row_fn(x, u, s, num_servers, style=style)
+                l_row, u_row = lu_block_row_jit(x, u, s, num_servers,
+                                                style=style)
             b = n // num_servers
             sl = slice(s * b, (s + 1) * b)
             if batched:

@@ -316,6 +316,39 @@ def test_threadpool_matches_inline_every_input_kind():
                                            rtol=1e-12)
 
 
+def test_warm_single_matrix_call_compiles_nothing():
+    """A second same-shape single-matrix call is cached dispatches only —
+    no trace, lowering or backend compile (the jax.monitoring events the
+    benchmark's compile_s_per_answer sums) — and the same det, bit for
+    bit."""
+    import threading
+
+    import jax
+
+    compile_events = {
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "/jax/core/compile/backend_compile_duration",
+    }
+    m = _wellcond(32, seed=59)
+    first = outsource_determinant(m, N)
+    me, seen = threading.get_ident(), []
+
+    def on_event(event, duration, **_):
+        if event in compile_events and threading.get_ident() == me:
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        second = outsource_determinant(m, N)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert seen == []
+    assert second.verified
+    assert (second.det.sign, second.det.logabs) == (first.det.sign,
+                                                    first.det.logabs)
+
+
 def test_session_roles_drive_manually():
     """The role API without the facade: client opens a session, an
     EdgeServer farm executes the relay task by task, the session collects

@@ -217,6 +217,32 @@ def test_lu_block_row_matches_honest_rows():
         )
 
 
+@pytest.mark.parametrize("batch", [None, 3], ids=["single", "stack"])
+def test_jitted_recompute_bit_matches_jitted_sweep(batch):
+    """The splice contract at either rank: the jitted strip recompute
+    replays the jitted inline sweep bit for bit, the culprit's strip
+    included (b = 64 takes the blocked panel)."""
+    from repro.api.transport import _lu_sweep
+    from repro.distrib.recovery import lu_block_row_jit
+
+    n, culprit = 256, 2
+    x = jnp.asarray(_wellcond(n, seed=53, batch=batch), dtype=jnp.float32)
+    plan = (ServerFault(server=culprit, mode="block", target="lu"),)
+    l, u = _lu_sweep(x, num_servers=N)
+    _, uf = _lu_sweep(x, num_servers=N, faults=plan)
+    b = n // N
+    rows = slice(culprit * b, (culprit + 1) * b)
+    assert not np.array_equal(np.asarray(uf[..., rows, :]),
+                              np.asarray(u[..., rows, :]))
+    for s in range(culprit + 1):
+        lr, ur = lu_block_row_jit(x, uf, s, N)
+        rows = slice(s * b, (s + 1) * b)
+        np.testing.assert_array_equal(np.asarray(lr),
+                                      np.asarray(l[..., rows, :]))
+        np.testing.assert_array_equal(np.asarray(ur),
+                                      np.asarray(u[..., rows, :]))
+
+
 def test_lu_block_row_ignores_corrupted_own_and_downstream_rows():
     """The recompute must be a function of x and the rows ABOVE only."""
     n = 24
