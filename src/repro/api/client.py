@@ -616,6 +616,7 @@ class Session:
         """
         transport = self._resolve_transport(transport)
         self._style = transport.style
+        x_aug, scatter_s = self._scatter(transport)
         # the span ends once the factors are on the device
         with span("spdc.sweep", wait=lambda: (l, u)) as sweep:
             if self.num_strips is not None:
@@ -631,13 +632,25 @@ class Session:
                 l = jnp.asarray(l_host, dtype=dt)
                 u = jnp.asarray(u_host, dtype=dt)
             elif transport.fused:
-                l, u = transport.sweep(self.x_aug, self.num_servers,
+                l, u = transport.sweep(x_aug, self.num_servers,
                                        faults=self.plan)
             else:
                 results = transport.factor(self.tasks(), faults=self.plan)
                 l, u = self._assemble(results)
-        self._dispatch_s = sweep.seconds
+        self._dispatch_s = scatter_s + sweep.seconds
         return self.collect((l, u), transport=transport)
+
+    def _scatter(self, transport):
+        """(the ciphertext where the transport's sweep wants it, seconds):
+        placed by `transport.place` under its own `spdc.scatter` span, a
+        sibling of `spdc.sweep`. Transports with nothing to place (and
+        rateless sessions) get `x_aug` as it is, in no time."""
+        if transport.place is None or self.num_strips is not None:
+            return self.x_aug, 0.0
+        # the span ends once the block rows are on their chips
+        with span("spdc.scatter", wait=lambda: x_aug) as scatter:
+            x_aug = transport.place(self.x_aug, self.num_servers)
+        return x_aug, scatter.seconds
 
     def start(self, transport=None) -> "PendingResult":
         """Nonblocking dispatch: ship this session's Parallelize stage
@@ -678,10 +691,11 @@ class Session:
 
             future = Future()
             try:
+                x_aug, scatter_s = self._scatter(transport)
                 with span("spdc.sweep", wait=lambda: out) as sweep:
-                    out = transport.sweep(self.x_aug, self.num_servers,
+                    out = transport.sweep(x_aug, self.num_servers,
                                           faults=self.plan)
-                self._dispatch_s = sweep.seconds
+                self._dispatch_s = scatter_s + sweep.seconds
                 future.set_result(out)
             except Exception as e:  # noqa: BLE001 — future carries it
                 future.set_exception(e)

@@ -10,8 +10,10 @@ messages; they differ in what the wire physically is:
     throughput path). ShardTasks still exist (`Session.tasks()`), the
     fused path just never materializes them.
   * ``ShardMapTransport``    — the distrib.spdc_pipeline shard_map
-    program: one JAX mesh device per server, the relay a real
-    `lax.ppermute`. Fused like inline (the sweep is one SPMD program).
+    program: the servers on the JAX mesh's chips (k = N / chips per
+    chip), the relay a real `lax.ppermute` between chips. Fused like
+    inline (the sweep is one SPMD program), after a placement phase of
+    its own (`place`).
   * ``ThreadPoolTransport``  — one EdgeServer object per worker slot,
     tasks executed on a thread pool, the relay threaded between them as
     in-memory messages. The cheapest transport with a real
@@ -188,11 +190,15 @@ class Transport:
         program and `Session` should skip task materialization.
     style: the core.lu.lu_block_row operation order this transport's
         factors follow — what repair recomputes must replay.
+    place: None, or on a fused transport whose servers sit on chips of
+        their own, `place(x_aug, num_servers)` → the ciphertext on those
+        chips, a phase that `Session` runs (and times) before `sweep()`.
     """
 
     name = "abstract"
     fused = False
     style = "nserver"
+    place = None
 
     _closed = False
     _driver_pool = None
@@ -377,10 +383,15 @@ class InlineTransport(Transport):
 
 
 class ShardMapTransport(Transport):
-    """distrib.spdc_pipeline as a transport: one mesh device per server,
-    the relay a real lax.ppermute (DESIGN.md §2). Fused — the sweep is a
-    single SPMD program; repairs recompute host-side in the pipeline's
-    operation order ("pipeline" style), exactly as recovery always has.
+    """distrib.spdc_pipeline as a transport: the servers on the chips of a
+    1-D mesh, k = N / chips consecutive servers per chip, the relay a real
+    lax.ppermute where it crosses chips (DESIGN.md §2). Fused — the sweep
+    is a single SPMD program; repairs recompute host-side in the
+    pipeline's operation order ("pipeline" style), exactly as recovery
+    always has.
+
+    `place` is a phase of its own: it puts the ciphertext's block rows on
+    their servers' chips before the sweep (the `spdc.scatter` span).
     """
 
     name = "shardmap"
@@ -389,6 +400,13 @@ class ShardMapTransport(Transport):
 
     def __init__(self, program: str = "baseline"):
         self.program = program
+
+    def place(self, x_aug, num_servers: int):
+        self._ensure_open()
+        from repro.distrib.spdc_pipeline import relay_sharding
+
+        return jax.device_put(x_aug, relay_sharding(
+            num_servers, x_aug.ndim == 3, program=self.program))
 
     def sweep(self, x_aug, num_servers: int, faults=()):
         self._ensure_open()

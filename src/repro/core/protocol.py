@@ -83,10 +83,11 @@ class SessionTimings:
     border) up to the ciphertext on the device; dispatch_s is the
     Parallelize stage as the client saw it, up to the factors on the
     device — for message transports, dominated by wire time. They are
-    the seconds of the `spdc.pmop` and `spdc.sweep` spans (repro.spans,
-    DESIGN.md §10.5). collect_s is the RRVP tail (authenticate → recovery
-    → decipher: the `spdc.verify` and `spdc.decipher` spans and the
-    bookkeeping around them). With the
+    the seconds of the `spdc.pmop` span, and of the `spdc.scatter` (where
+    the transport places the ciphertext on its chips) and `spdc.sweep`
+    spans (repro.spans, DESIGN.md §10.5). collect_s is the RRVP tail
+    (authenticate → recovery → decipher: the `spdc.verify` and
+    `spdc.decipher` spans and the bookkeeping around them). With the
     async-overlap API (`Session.start` / `SPDCClient.run_pipelined`,
     DESIGN.md §9) batch k+1's pmop_s runs INSIDE batch k's dispatch_s —
     the sum of phases across a pipelined run exceeds its wall clock,
@@ -427,9 +428,10 @@ def outsource_determinant(
         DESIGN.md §1.1.4).
     use_kernel: route Cipher through the fused Pallas CED kernel instead
         of the jnp oracle (TPU target; interpret-mode on CPU).
-    distributed: route Parallelize through the shard_map pipeline — every
-        mesh device plays one edge server (requires >= num_servers JAX
-        devices); equivalent to transport="shardmap". See DESIGN.md §2.
+    distributed: route Parallelize through the shard_map pipeline — the
+        edge servers on the process's devices, N / chips consecutive
+        servers per chip; equivalent to transport="shardmap". See
+        DESIGN.md §2.
     faithful_sign: reproduce the paper's literal (−1)^k rotation sign in
         Decipher instead of the Panth Rotation Theorem's case split —
         wrong for n ≡ 0,1 (mod 4); kept for faithfulness studies

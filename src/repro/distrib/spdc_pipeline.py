@@ -1,35 +1,43 @@
 """Distributed N-server SPDC LU — paper Algorithm 3 as a shard_map pipeline.
 
-Mapping (DESIGN.md §2): edge server i ⇒ mesh device i on a 1-D "servers"
-axis. Server i owns block row i of the ciphered matrix (in_specs
-P("servers", None)). The paper's one-way communication pattern — S_i sends
-its accumulated U rows only to S_{i+1} — becomes a single forward
-`lax.ppermute` per round: neighbor-only ICI traffic, no broadcast, no
-all-gather, exactly the paper's §IV.D.3 schedule.
+Mapping (DESIGN.md §2): the N edge servers lie on a 1-D "servers" axis of
+D chips, k = N/D consecutive servers per chip — server s on chip s // k.
+D is the largest divisor of N that is at most the process's device count
+(`relay_chips`), so k = 1 wherever there is a device per server. A chip
+owns the block rows of its servers, a (k·b, n) slab of the ciphered
+matrix (in_specs P("servers", None)). The paper's one-way communication
+pattern — S_i sends its accumulated U rows only to S_{i+1} — is a local
+write into the chip's U buffer between two servers on one chip, and a
+single forward `lax.ppermute` where the chain crosses to the next chip:
+neighbor-only ICI traffic, no broadcast, no all-gather, the paper's
+§IV.D.3 schedule.
 
-Program structure (SPMD, N rounds):
+Program structure (SPMD, D chip-rounds):
 
-  round t:  device with axis_index == t runs its Alg.-3 row computation
-            (L_{t,0..t-1} via TRSM against upstream U; blocked-panel LU of
-            the Schur-updated diagonal block; its U row), writes the U row
-            into the relay buffer; then every device forwards the relay
-            buffer one hop down the ring.
+  chip-round d:  the chip with axis_index == d runs its k servers in
+                 order; server t = d·k + j runs its Alg.-3 row computation
+                 (L_{t,0..t-1} via TRSM against upstream U; blocked-panel
+                 LU of the Schur-updated diagonal block; its U row) and
+                 writes the U row into the chip's relay buffer, where
+                 server t+1 reads it; then every chip forwards the relay
+                 buffer one hop down the ring.
 
 Batch semantics (DESIGN.md §3): every program accepts a device-local block
-of shape (b, n) — one matrix — or (B, b, n) — a stack. The batch dimension
-stays device-local (in_specs P(None, "servers", None)); the "servers" axis
-and the relay schedule are unchanged, so a single N-round wavefront sweep
-factors all B matrices: the N-1 relay hops are paid once per batch instead
-of once per matrix.
+of shape (k·b, n) — one matrix — or (B, k·b, n) — a stack. The batch
+dimension stays device-local (in_specs P(None, "servers", None)); the
+"servers" axis and the relay schedule are unchanged, so a single
+wavefront sweep factors all B matrices: the relay hops are paid once per
+batch instead of once per matrix.
 
 The relay buffer is the fixed-shape (n, n) U matrix (rows ≥ t still zero).
 The paper's variable-size messages (rows 0..t only) would be a ragged
 send; fixed-shape relay overcounts bytes by ≤ 2× — accounted for in
 benchmarks (CommLog tracks the paper-exact volume).
 
-The per-device active computation is gated behind `lax.cond` on the traced
-axis index, so passive devices do no FLOPs while the wavefront is
+The per-chip active computation is gated behind `lax.cond` on the traced
+axis index, so passive chips do no FLOPs while the wavefront is
 elsewhere — faithful to the paper's staggered activation (§IV.D.3).
+The "exact" and "stream" programs run one server per device only.
 """
 from __future__ import annotations
 
@@ -38,7 +46,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import AxisType
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.lu import precise_matmul
@@ -66,29 +74,35 @@ def _trsm_right_upper_b(u: jnp.ndarray, acc: jnp.ndarray) -> jnp.ndarray:
     return _trsm_right_upper(u, acc)
 
 
-def _inject_faults(l_row, u_row, my_id, faults, *, n, batched):
+def _inject_faults(l_row, u_row, my_id, faults, *, n, batched, per_chip=1):
     """Device-output fault injection (core.faults surface, distributed leg).
 
-    The mesh device playing the faulty server corrupts the (B, b, n) strips
-    it reports — tamper modes and dropouts are first-class on the real
-    pipeline, not just the single-process simulation. Faults are static
-    (part of the compile cache key); the injection is a `where` on the
-    traced axis index, so honest devices' outputs pass through untouched.
-    In-band relay poisoning is NOT modeled here (see core.lu.lu_nserver).
+    The mesh device holding the faulty server corrupts the (B, b, n) strip
+    that server reports — tamper modes and dropouts are first-class on the
+    real pipeline, not just the single-process simulation. With k =
+    `per_chip` servers per chip, l_row / u_row are the chip's (B, k·b, n)
+    slabs and server s is slot s % k of chip s // k. Faults are static (part of the compile
+    cache key); the injection is a `where` on the traced axis index, so
+    honest devices' outputs pass through untouched. In-band relay
+    poisoning is NOT modeled here (see core.lu.lu_nserver).
     """
     import numpy as np
 
     from repro.core.faults import corrupt_strip
 
+    b = l_row.shape[-2] // per_chip
     for f in faults:
         targets = ("l", "u") if f.kind == "dropout" else tuple(f.target)
+        chip, slot = divmod(f.server, per_chip)
+        rows = slice(slot * b, (slot + 1) * b)
 
-        def masked(orig, factor, f=f):
-            bad = corrupt_strip(orig, f, n=n, factor=factor)
+        def masked(orig, factor, f=f, rows=rows):
+            strip = orig[:, rows]
+            bad = corrupt_strip(strip, f, n=n, factor=factor)
             if f.matrices is not None and batched:
                 idx = np.asarray(f.matrices, dtype=np.int32)
-                bad = orig.at[idx].set(bad[idx])
-            return jnp.where(my_id == f.server, bad, orig)
+                bad = strip.at[idx].set(bad[idx])
+            return jnp.where(my_id == chip, orig.at[:, rows].set(bad), orig)
 
         if "l" in targets:
             l_row = masked(l_row, "l")
@@ -97,74 +111,101 @@ def _inject_faults(l_row, u_row, my_id, faults, *, n, batched):
     return l_row, u_row
 
 
-def _server_program(x_blk: jnp.ndarray, *, n: int, b: int, num_servers: int,
-                    axis: str, faults=()) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Runs on every device inside shard_map. x_blk: (b, n) or (B, b, n)."""
-    my_id = lax.axis_index(axis)
-    x_row, batched = _batched_view(x_blk, b, n)
+def _serve_row(t, x_row, args, *, n: int, b: int):
+    """Server t's Alg.-3 row in the relay's operation order: x_row its
+    (B, b, n) block row, args = (u_buf, l_row, u_row) with u_buf the
+    (B, n, n) relay buffer holding the U rows of servers 0..t-1 (zeros
+    below) and t a traced int32. Returns the buffer with server t's U row
+    written in (the one-way hand-off to server t+1) and its strips."""
+    u_buf, l_row, u_row = args  # (B,n,n), (B,b,n), (B,b,n)
     B = x_row.shape[0]
     zero = jnp.zeros((), jnp.int32)
 
+    # --- L_{i,k} for k < i (sequential in k; TRSM vs upstream U_kk) ---
+    def lblk(k, l_row):
+        kb = (k * b).astype(jnp.int32)
+        # slice the U column panel FIRST: O(b·n·b) per step instead of
+        # recomputing the full (b,n) product (§Perf C2 — 16x fewer flops
+        # in the L-row loop)
+        u_col = lax.dynamic_slice(u_buf, (zero, zero, kb), (B, n, b))
+        acc = (lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b))
+               - precise_matmul(l_row, u_col))
+        ukk = lax.dynamic_slice(u_buf, (zero, kb, kb), (B, b, b))
+        lik = _trsm_right_upper_b(ukk, acc)
+        return lax.dynamic_update_slice(l_row, lik, (zero, zero, kb))
+
+    l_row = lax.fori_loop(0, t, lblk, l_row)
+
+    # --- Schur update of the whole row, blocked-panel LU of the diag ---
+    s = x_row - precise_matmul(l_row, u_buf)
+    ib = (t * b).astype(jnp.int32)
+    sii = lax.dynamic_slice(s, (zero, zero, ib), (B, b, b))
+    lii, uii = _factor_diag(sii)
+    l_row = lax.dynamic_update_slice(l_row, lii, (zero, zero, ib))
+
+    # --- U_{i,j} for j >= i, vectorized over the full row ---
+    r = jax.scipy.linalg.solve_triangular(lii, s, lower=True, unit_diagonal=True)
+    cols = lax.broadcasted_iota(jnp.int32, (B, b, n), 2)
+    u_row = jnp.where(cols >= ib, r, jnp.zeros_like(r))
+    u_buf = lax.dynamic_update_slice(u_buf, u_row, (zero, ib, zero))
+    return u_buf, l_row, u_row
+
+
+def _server_program(x_blk: jnp.ndarray, *, n: int, b: int, num_servers: int,
+                    axis: str, faults=()) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Runs on every chip inside shard_map. x_blk: (k·b, n) or (B, k·b, n),
+    the block rows of the chip's k = N / chips servers (`per_chip`)."""
+    per_chip = x_blk.shape[-2] // b
+    chips = num_servers // per_chip
+    my_chip = lax.axis_index(axis)
+    x_slab, batched = _batched_view(x_blk, per_chip * b, n)
+    B = x_slab.shape[0]
+    zero = jnp.zeros((), jnp.int32)
+
+    def server_j(j, args):
+        """Slot j of the active chip: server my_chip·k + j. The buffer it
+        writes is the hand-off to slot j+1 on the same chip."""
+        u_buf, l_slab, u_slab = args
+        at = (zero, (j * b).astype(jnp.int32), zero)
+        strip = (u_buf, lax.dynamic_slice(l_slab, at, (B, b, n)),
+                 lax.dynamic_slice(u_slab, at, (B, b, n)))
+        x_row = lax.dynamic_slice(x_slab, at, (B, b, n))
+        u_buf, l_row, u_row = _serve_row(my_chip * per_chip + j, x_row,
+                                         strip, n=n, b=b)
+        return (u_buf, lax.dynamic_update_slice(l_slab, l_row, at),
+                lax.dynamic_update_slice(u_slab, u_row, at))
+
     def active(args):
-        u_buf, l_row, u_row = args  # (B,n,n), (B,b,n), (B,b,n)
-
-        # --- L_{i,k} for k < i (sequential in k; TRSM vs upstream U_kk) ---
-        def lblk(k, l_row):
-            kb = (k * b).astype(jnp.int32)
-            # slice the U column panel FIRST: O(b·n·b) per step instead of
-            # recomputing the full (b,n) product (§Perf C2 — 16x fewer flops
-            # in the L-row loop)
-            u_col = lax.dynamic_slice(u_buf, (zero, zero, kb), (B, n, b))
-            acc = (lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b))
-                   - precise_matmul(l_row, u_col))
-            ukk = lax.dynamic_slice(u_buf, (zero, kb, kb), (B, b, b))
-            lik = _trsm_right_upper_b(ukk, acc)
-            return lax.dynamic_update_slice(l_row, lik, (zero, zero, kb))
-
-        l_row = lax.fori_loop(0, my_id, lblk, l_row)
-
-        # --- Schur update of the whole row, blocked-panel LU of the diag ---
-        s = x_row - precise_matmul(l_row, u_buf)
-        ib = (my_id * b).astype(jnp.int32)
-        sii = lax.dynamic_slice(s, (zero, zero, ib), (B, b, b))
-        lii, uii = _factor_diag(sii)
-        l_row = lax.dynamic_update_slice(l_row, lii, (zero, zero, ib))
-
-        # --- U_{i,j} for j >= i, vectorized over the full row ---
-        r = jax.scipy.linalg.solve_triangular(lii, s, lower=True, unit_diagonal=True)
-        cols = lax.broadcasted_iota(jnp.int32, (B, b, n), 2)
-        u_row = jnp.where(cols >= ib, r, jnp.zeros_like(r))
-        u_buf = lax.dynamic_update_slice(u_buf, u_row, (zero, ib, zero))
-        return u_buf, l_row, u_row
+        return lax.fori_loop(0, per_chip, server_j, args)
 
     def passive(args):
         return args
 
-    fwd = [(i, (i + 1) % num_servers) for i in range(num_servers)]
+    fwd = [(i, (i + 1) % chips) for i in range(chips)]
 
-    def round_fn(t, state):
+    def round_fn(d, state):
         u_buf, l_row, u_row = state
         u_buf, l_row, u_row = lax.cond(
-            my_id == t, active, passive, (u_buf, l_row, u_row)
+            my_chip == d, active, passive, (u_buf, l_row, u_row)
         )
-        # one-way relay S_t -> S_{t+1} (ring hop; only the t -> t+1 edge
+        # one-way relay to the next chip (ring hop; only the d -> d+1 edge
         # carries fresh data, matching the paper's single send per phase)
         u_buf = lax.ppermute(u_buf, axis, fwd)
         return u_buf, l_row, u_row
 
-    u_buf0 = jnp.zeros((B, n, n), dtype=x_row.dtype)
-    l_row0 = jnp.zeros((B, b, n), dtype=x_row.dtype)
-    u_row0 = jnp.zeros((B, b, n), dtype=x_row.dtype)
+    u_buf0 = jnp.zeros((B, n, n), dtype=x_slab.dtype)
+    l_row0 = jnp.zeros((B, per_chip * b, n), dtype=x_slab.dtype)
+    u_row0 = jnp.zeros((B, per_chip * b, n), dtype=x_slab.dtype)
     # carries become device-varying inside the loop; mark them so upfront
     u_buf0, l_row0, u_row0 = lax.pcast(
         (u_buf0, l_row0, u_row0), (axis,), to="varying"
     )
     _, l_row, u_row = lax.fori_loop(
-        0, num_servers, round_fn, (u_buf0, l_row0, u_row0)
+        0, chips, round_fn, (u_buf0, l_row0, u_row0)
     )
     if faults:
-        l_row, u_row = _inject_faults(l_row, u_row, my_id, faults, n=n,
-                                      batched=batched)
+        l_row, u_row = _inject_faults(l_row, u_row, my_chip, faults, n=n,
+                                      batched=batched, per_chip=per_chip)
     if not batched:
         return l_row[0], u_row[0]
     return l_row, u_row
@@ -314,27 +355,59 @@ _PROGRAMS = {
 }
 
 
+def relay_chips(num_servers: int, devices: int, program: str = "baseline") -> int:
+    """Chips the relay of `num_servers` servers runs on, given `devices`:
+    the largest divisor of N that is at most the device count, so each
+    chip holds k = N / chips consecutive servers. The "exact" and "stream"
+    programs run one server per device and refuse N > devices."""
+    if program != "baseline":
+        if devices < num_servers:
+            raise ValueError(
+                f"the {program!r} relay program needs one device per server: "
+                f"N={num_servers}, but {devices} device(s); the baseline "
+                "program runs several servers per chip"
+            )
+        return num_servers
+    return max(d for d in range(1, min(num_servers, devices) + 1)
+               if num_servers % d == 0)
+
+
+@lru_cache(maxsize=None)
+def relay_sharding(num_servers: int, batched: bool, axis: str = "servers",
+                   program: str = "baseline") -> NamedSharding:
+    """Where the relay wants its input: block rows over the first
+    `relay_chips` devices of this process (P(axis, None), the batch
+    dimension device-local)."""
+    chips = relay_chips(num_servers, len(jax.devices()), program)
+    mesh = jax.make_mesh((chips,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=tuple(jax.devices()[:chips]))
+    return NamedSharding(mesh, P(None, axis, None) if batched else P(axis, None))
+
+
+def _shard_program(mesh, program: str, n: int, batched: bool,
+                   num_servers: int, axis: str, faults=()):
+    spec = P(None, axis, None) if batched else P(axis, None)
+    return jax.jit(jax.shard_map(
+        partial(_PROGRAMS[program], n=n, b=n // num_servers,
+                num_servers=num_servers, axis=axis, faults=faults),
+        mesh=mesh,
+        in_specs=spec,
+        out_specs=(spec, spec),
+    ))
+
+
 @lru_cache(maxsize=None)
 def _compiled_pipeline(program: str, n: int, batch: int | None,
                        num_servers: int, axis: str, faults=()):
-    """Build + jit one pipeline program on the default device mesh.
+    """Build + jit one pipeline program on this process's relay mesh
+    (`relay_sharding`).
 
     Cached so repeated protocol calls (the high-throughput serving path)
     reuse the compiled executable instead of re-tracing a fresh shard_map.
     """
-    devs = tuple(jax.devices()[:num_servers])
-    mesh = jax.make_mesh((num_servers,), (axis,),
-                         axis_types=(AxisType.Auto,), devices=devs)
-    b = n // num_servers
-    spec = P(None, axis, None) if batch is not None else P(axis, None)
-    fn = jax.shard_map(
-        partial(_PROGRAMS[program], n=n, b=b, num_servers=num_servers,
-                axis=axis, faults=faults),
-        mesh=mesh,
-        in_specs=spec,
-        out_specs=(spec, spec),
-    )
-    return jax.jit(fn)
+    mesh = relay_sharding(num_servers, batch is not None, axis, program).mesh
+    return _shard_program(mesh, program, n, batch is not None, num_servers,
+                          axis, faults)
 
 
 def lu_nserver_shardmap(
@@ -349,13 +422,15 @@ def lu_nserver_shardmap(
     factors the whole stack (DESIGN.md §3).
 
     faults: a FaultPlan (core.faults) injected at the device-output level:
-    the mesh device playing each faulty server corrupts (or zeroes) the
-    strips it reports. Delay faults must be resolved by the caller
+    the mesh device holding each faulty server corrupts (or zeroes) the
+    strip it reports. Delay faults must be resolved by the caller
     (core.faults.resolve_delays); in-band relay poisoning is only modeled
     by the single-process simulation and is rejected here.
 
-    mesh: optional existing mesh containing `axis`; default builds a 1-D
-    mesh over the first num_servers devices of this process.
+    mesh: optional existing mesh containing `axis`, whose size must divide
+    N (k = N / size servers per chip); default: `relay_chips` of this
+    process's devices. The "exact" and "stream" programs take one server
+    per device.
 
     (The deprecated `exact_relay=` bool shim completed its cycle and was
     removed — passing it now raises TypeError.)
@@ -384,24 +459,17 @@ def lu_nserver_shardmap(
     batch = x.shape[0] if x.ndim == 3 else None
 
     if mesh is None:
-        devices = jax.devices()
-        if len(devices) < num_servers:
-            raise ValueError(
-                f"the shard_map pipeline needs one device per server: "
-                f"N={num_servers}, but the {devices[0].platform} backend "
-                f"has {len(devices)} device(s)"
-            )
         fn = _compiled_pipeline(program, n, batch, num_servers, axis, faults)
     else:
-        b = n // num_servers
-        spec = P(None, axis, None) if batch is not None else P(axis, None)
-        fn = jax.jit(jax.shard_map(
-            partial(_PROGRAMS[program], n=n, b=b, num_servers=num_servers,
-                    axis=axis, faults=faults),
-            mesh=mesh,
-            in_specs=spec,
-            out_specs=(spec, spec),
-        ))
+        chips = mesh.shape[axis]
+        if num_servers % chips:
+            raise ValueError(
+                f"a mesh axis of {chips} device(s) does not divide "
+                f"N={num_servers} servers into whole servers per chip"
+            )
+        relay_chips(num_servers, chips, program)  # exact/stream: k = 1 only
+        fn = _shard_program(mesh, program, n, batch is not None, num_servers,
+                            axis, faults)
     l, u = fn(x)
     return l, u
 

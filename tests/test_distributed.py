@@ -17,14 +17,68 @@ def _wellcond(n, seed=0):
     return jnp.asarray(rng.standard_normal((n, n)) + n * np.eye(n))
 
 
-@pytest.mark.parametrize("program", ["baseline", "exact", "stream"])
-@pytest.mark.parametrize("n,servers", [(16, 4), (24, 8), (32, 2), (40, 5)])
-def test_shardmap_matches_reference(n, servers, program):
+PROGRAMS = ["baseline", "exact", "stream"]
+#: (n, N): one server per device
+ONE_PER_DEVICE = [(16, 4), (24, 8), (32, 2), (40, 5)]
+#: (n, N, chips): k = N / chips servers share each chip of the mesh
+SHARED_CHIPS = [(64, 16, 4), (48, 8, 4), (40, 10, 2), (96, 16, 8)]
+
+
+def _mesh(chips):
+    from repro.launch.mesh import make_smoke_mesh
+
+    return make_smoke_mesh((chips,), ("servers",))
+
+
+@pytest.mark.parametrize("n,servers,chips,program,batch", [
+    *(pytest.param(n, s, None, p, None, id=f"{n}-{s}-{p}")
+      for p in PROGRAMS for n, s in ONE_PER_DEVICE),
+    *(pytest.param(n, s, c, "baseline", None, id=f"{n}-{s}-chips{c}-baseline")
+      for n, s, c in SHARED_CHIPS),
+    pytest.param(64, 16, 4, "baseline", 3, id="64-16-chips4-baseline-stack3"),
+])
+def test_shardmap_matches_reference(n, servers, chips, program, batch):
     x = _wellcond(n, seed=servers)
-    l, u = lu_nserver_shardmap(x, servers, program=program)
+    if batch is not None:
+        x = jnp.stack([_wellcond(n, seed=servers + i) for i in range(batch)])
+    mesh = None if chips is None else _mesh(chips)
+    l, u = lu_nserver_shardmap(x, servers, program=program, mesh=mesh)
     l2, u2, _ = lu_nserver(x, servers)
     np.testing.assert_allclose(np.asarray(l), np.asarray(l2), atol=1e-9)
     np.testing.assert_allclose(np.asarray(u), np.asarray(u2), atol=1e-9)
+
+
+def test_relay_chips_is_the_largest_divisor_within_the_devices():
+    from repro.distrib.spdc_pipeline import relay_chips
+
+    assert [relay_chips(16, d) for d in (1, 2, 3, 4, 8, 16, 32)] == \
+        [1, 2, 2, 4, 8, 16, 16]
+    assert relay_chips(10, 8) == 5 and relay_chips(7, 4) == 1
+    assert relay_chips(4, 8, "exact") == 4
+
+
+def test_sixteen_servers_on_the_default_devices_match_the_reference():
+    """N=16 over this process's 8 devices: two servers per chip, chosen
+    from the device count alone."""
+    x = _wellcond(64, seed=16)
+    l, u = lu_nserver_shardmap(x, 16)
+    assert len(l.sharding.device_set) == 8
+    l2, u2, _ = lu_nserver(x, 16)
+    np.testing.assert_allclose(np.asarray(l), np.asarray(l2), atol=1e-9)
+    np.testing.assert_allclose(np.asarray(u), np.asarray(u2), atol=1e-9)
+
+
+@pytest.mark.parametrize("program", ["exact", "stream"])
+@pytest.mark.parametrize("chips", [None, 4])
+def test_exact_and_stream_refuse_more_servers_than_chips(program, chips):
+    mesh = None if chips is None else _mesh(chips)
+    with pytest.raises(ValueError, match="one device per server"):
+        lu_nserver_shardmap(_wellcond(64), 16, program=program, mesh=mesh)
+
+
+def test_a_mesh_that_does_not_divide_the_servers_is_refused():
+    with pytest.raises(ValueError, match="does not divide"):
+        lu_nserver_shardmap(_wellcond(48), 8, mesh=_mesh(3))
 
 
 def test_shardmap_exact_relay_shim_removed():
@@ -43,22 +97,16 @@ def test_shardmap_rejects_unknown_program():
         lu_nserver_shardmap(_wellcond(16), 4, program="telepathy")
 
 
-def test_shardmap_hlo_is_one_way():
-    """The distributed pipeline must contain collective-permutes (the
-    one-way relay) and no all-gather/all-reduce (no broadcast pattern)."""
-    n, servers = 16, 4
+def _assert_one_way(n, servers, chips):
     from functools import partial
 
     from repro.distrib.spdc_pipeline import _server_program
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import make_smoke_mesh
-
-    mesh = make_smoke_mesh((servers,), ("servers",))
     fn = jax.shard_map(
         partial(_server_program, n=n, b=n // servers, num_servers=servers,
                 axis="servers"),
-        mesh=mesh, in_specs=P("servers", None),
+        mesh=_mesh(chips), in_specs=P("servers", None),
         out_specs=(P("servers", None), P("servers", None)),
     )
     txt = jax.jit(fn).lower(
@@ -69,9 +117,31 @@ def test_shardmap_hlo_is_one_way():
     assert "all-reduce" not in txt
 
 
+def test_shardmap_hlo_is_one_way():
+    """The distributed pipeline must contain collective-permutes (the
+    one-way relay) and no all-gather/all-reduce (no broadcast pattern)."""
+    _assert_one_way(16, 4, 4)
+
+
+def test_shardmap_hlo_is_one_way_with_four_servers_per_chip():
+    """Servers that share a chip hand off through its U buffer; between
+    chips the relay is still collective-permutes only."""
+    _assert_one_way(64, 16, 4)
+
+
 def test_distributed_protocol_end_to_end():
     m = np.asarray(_wellcond(24, seed=3))
     res = outsource_determinant(m, 4, distributed=True)
+    want_s, want_la = np.linalg.slogdet(m)
+    assert res.verified and res.det.sign == want_s
+    np.testing.assert_allclose(res.det.logabs, want_la, rtol=1e-9)
+
+
+def test_sixteen_servers_through_the_shardmap_transport():
+    """outsource_determinant(M, 16, transport="shardmap") on 8 devices:
+    verified by Q3, the ciphertext placed on the relay's chips first."""
+    m = np.asarray(_wellcond(96, seed=5))
+    res = outsource_determinant(m, 16, transport="shardmap", method="q3")
     want_s, want_la = np.linalg.slogdet(m)
     assert res.verified and res.det.sign == want_s
     np.testing.assert_allclose(res.det.logabs, want_la, rtol=1e-9)

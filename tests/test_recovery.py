@@ -146,6 +146,59 @@ def test_recovery_distributed_pipeline():
     np.testing.assert_allclose(res.det.logabs, honest.det.logabs, rtol=1e-10)
 
 
+@pytest.mark.parametrize("mode,target", [("block", "lu"), ("single", "u")])
+def test_recovery_heals_a_server_on_a_shared_chip(mode, target):
+    """Server 5 of 16 on a 4-chip mesh (slot 1 of chip 1, k = 4) tampers:
+    Q3 localizes it, and the "pipeline" recompute heals its strip alone.
+    The spliced strip is that recompute bit for bit, and the honest
+    relay's strip to rounding (XLA:CPU rounds the relay's products in
+    another order than a recompute outside it, at k = 1 as at k = 4)."""
+    from repro.distrib.recovery import lu_block_row_jit
+    from repro.distrib.spdc_pipeline import lu_nserver_shardmap
+    from repro.launch.mesh import make_smoke_mesh
+
+    n, servers, culprit = 128, 16, 5
+    x = jnp.asarray(_wellcond(n, seed=59))
+    mesh = make_smoke_mesh((4,), ("servers",))
+    l, u = lu_nserver_shardmap(x, servers, mesh=mesh)
+    fault = ServerFault(server=culprit, mode=mode, target=target)
+    lf, uf = lu_nserver_shardmap(x, servers, mesh=mesh, faults=(fault,))
+    b = n // servers
+    rows = slice(culprit * b, (culprit + 1) * b)
+    bad = np.any(np.asarray(uf) != np.asarray(u), axis=1)
+    assert bad.any() and not bad[: rows.start].any() and not bad[rows.stop:].any()
+
+    lh, uh, verdict, report = recover_lu(
+        lf, uf, x, num_servers=servers, method="q3", standby=1,
+        style="pipeline",
+    )
+    assert bool(np.all(verdict.ok)) and report.ok
+    assert report.events[0].server == culprit
+    assert report.servers_replaced == (culprit,)
+    lr, ur = lu_block_row_jit(x, uf, culprit, servers, style="pipeline")
+    np.testing.assert_array_equal(np.asarray(lh[rows]), np.asarray(lr))
+    np.testing.assert_array_equal(np.asarray(uh[rows]), np.asarray(ur))
+    np.testing.assert_allclose(np.asarray(uh), np.asarray(u), atol=1e-12)
+    np.testing.assert_allclose(np.asarray(lh), np.asarray(l), atol=1e-12)
+
+
+def test_recovery_through_the_shardmap_transport_on_shared_chips():
+    """outsource_determinant(M, 16, transport="shardmap") on 8 devices (two
+    servers per chip) with server 5 dropping out heals to the honest
+    determinant."""
+    n = 64
+    m = _wellcond(n, seed=61)
+    honest = outsource_determinant(m, 16)
+    res = outsource_determinant(
+        m, 16, transport="shardmap",
+        faults=ServerFault(server=5, kind="dropout"),
+        recover=True, standby=1,
+    )
+    assert res.verified and res.report.recovery.ok
+    assert res.report.recovery.events[0].server == 5
+    np.testing.assert_allclose(res.det.logabs, honest.det.logabs, rtol=1e-10)
+
+
 def test_recovery_in_band_cascade():
     """Relay poisoning: the tampered U row was consumed downstream, so the
     scheduler heals one block row per round — and still converges to the

@@ -138,3 +138,41 @@ def test_session_timings_are_the_spans_seconds(monkeypatch):
     assert t.pmop_s == by["spdc.pmop"] and t.dispatch_s == by["spdc.sweep"]
     assert t.collect_s >= by["spdc.verify"] + by["spdc.decipher"]
     assert t.total_s == pytest.approx(t.pmop_s + t.dispatch_s + t.collect_s)
+
+
+@pytest.mark.parametrize("call", ["run", "start"])
+def test_the_shardmap_transport_places_the_ciphertext_in_a_span_of_its_own(
+        monkeypatch, call):
+    """On the shard_map relay, `spdc.scatter` puts the block rows on their
+    servers' chips before `spdc.sweep` opens; the sweep gets the placed
+    array, and dispatch_s counts both spans."""
+    from repro.api import ShardMapTransport
+
+    seen, swept = [], []
+    real_exit = span.__exit__
+
+    def record_exit(self, *exc):
+        real_exit(self, *exc)
+        seen.append((self.name, self.seconds))
+
+    transport = ShardMapTransport()
+    real_sweep = transport.sweep
+
+    def spy_sweep(x_aug, num_servers, faults=()):
+        swept.append(x_aug)
+        return real_sweep(x_aug, num_servers, faults=faults)
+
+    monkeypatch.setattr(span, "__exit__", record_exit)
+    monkeypatch.setattr(transport, "sweep", spy_sweep)
+    session = SPDCClient().open_session(_wellcond(48, seed=6), 16)
+    res = getattr(session, call)(transport)
+    res = res if call == "run" else res.result()
+    assert res.verified
+    assert [name for name, _ in seen] == ["spdc.pmop", "spdc.scatter",
+                                          "spdc.sweep", "spdc.verify",
+                                          "spdc.decipher"]
+    (x,) = swept
+    assert len(x.sharding.device_set) == 8  # 16 servers, two per device
+    by = dict(seen)
+    assert res.report.timings.dispatch_s == pytest.approx(
+        by["spdc.scatter"] + by["spdc.sweep"])

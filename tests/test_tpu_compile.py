@@ -110,3 +110,27 @@ def test_lu_kernels_compile(one_chip, kernel):
     }[kernel]
     hlo = _compile(fn, *(_f32(one_chip, *s) for s in shapes))
     assert "tpu_custom_call" in hlo
+
+
+def test_relay_with_four_servers_per_chip_compiles(topo):
+    """The shard_map relay of 16 servers over the described host's four
+    chips (k = 4, b = 64 takes the blocked panel): one program, whose
+    only collectives are the hops between chips."""
+    from functools import partial
+
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distrib.spdc_pipeline import _server_program
+
+    n, servers = 1024, 16
+    mesh = Mesh(topo.devices, ("servers",))
+    spec = P("servers", None)
+    fn = jax.shard_map(
+        partial(_server_program, n=n, b=n // servers, num_servers=servers,
+                axis="servers"),
+        mesh=mesh, in_specs=spec, out_specs=(spec, spec),
+    )
+    txt = _compile(fn, _f32(NamedSharding(mesh, spec), n, n))
+    assert "collective-permute" in txt
+    assert "all-gather" not in txt and "all-reduce" not in txt
