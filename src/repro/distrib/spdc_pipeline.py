@@ -6,21 +6,28 @@ D is the largest divisor of N that is at most the process's device count
 (`relay_chips`), so k = 1 wherever there is a device per server. A chip
 owns the block rows of its servers, a (k·b, n) slab of the ciphered
 matrix (in_specs P("servers", None)). The paper's one-way communication
-pattern — S_i sends its accumulated U rows only to S_{i+1} — is a local
-write into the chip's U buffer between two servers on one chip, and a
-single forward `lax.ppermute` where the chain crosses to the next chip:
-neighbor-only ICI traffic, no broadcast, no all-gather, the paper's
-§IV.D.3 schedule.
+pattern — S_i passes U rows only to S_{i+1} — is a local update between
+two servers on one chip, and a forward `lax.ppermute` to the next chip
+where the chain crosses chips: neighbor-only ICI traffic on the
+non-cyclic edges i → i+1, no broadcast, no all-gather.
 
-Program structure (SPMD, D chip-rounds):
+Program structure (SPMD, a streaming right-looking wavefront of
+N + D − 1 steps). At step i chip c takes up U row r = i − c:
 
-  chip-round d:  the chip with axis_index == d runs its k servers in
-                 order; server t = d·k + j runs its Alg.-3 row computation
-                 (L_{t,0..t-1} via TRSM against upstream U; blocked-panel
-                 LU of the Schur-updated diagonal block; its U row) and
-                 writes the U row into the chip's relay buffer, where
-                 server t+1 reads it; then every chip forwards the relay
-                 buffer one hop down the ring.
+  own turn (r one of its servers): server r factors the diagonal block
+      of its row, which every earlier U row has already updated
+      (`lu_diag_factor`), and solves for its (b, n) U row;
+  relayed row (r < c·k): the row chip c−1 took up at step i−1, which
+      this step's hop brought;
+
+then the chip applies U row r to the rows of its servers after r at once
+(a rank-b update: L_{t,r} = S_t[:, r-block] U_rr⁻¹, S_t −= L_{t,r} U_r)
+and hands it to chip c+1 at the next step's hop. One hop carries one
+server's (B, b, n) U row; chip 0 receives zeros. The hop that forwards a
+row does not wait on the step's own turn, so the two can overlap, and a
+chip applies upstream rows while the wavefront is still upstream: every
+chip with servers left works at once. A server still learns only the U
+rows of the servers before it, each reaching it through its predecessors.
 
 Batch semantics (DESIGN.md §3): every program accepts a device-local block
 of shape (k·b, n) — one matrix — or (B, k·b, n) — a stack. The batch
@@ -29,15 +36,8 @@ dimension stays device-local (in_specs P(None, "servers", None)); the
 wavefront sweep factors all B matrices: the relay hops are paid once per
 batch instead of once per matrix.
 
-The relay buffer is the fixed-shape (n, n) U matrix (rows ≥ t still zero).
-The paper's variable-size messages (rows 0..t only) would be a ragged
-send; fixed-shape relay overcounts bytes by ≤ 2× — accounted for in
-benchmarks (CommLog tracks the paper-exact volume).
-
-The per-chip active computation is gated behind `lax.cond` on the traced
-axis index, so passive chips do no FLOPs while the wavefront is
-elsewhere — faithful to the paper's staggered activation (§IV.D.3).
-The "exact" and "stream" programs run one server per device only.
+The "exact" and "stream" programs run one server per device only, one
+active device per round behind `lax.cond` on the traced axis index.
 """
 from __future__ import annotations
 
@@ -111,104 +111,83 @@ def _inject_faults(l_row, u_row, my_id, faults, *, n, batched, per_chip=1):
     return l_row, u_row
 
 
-def _serve_row(t, x_row, args, *, n: int, b: int):
-    """Server t's Alg.-3 row in the relay's operation order: x_row its
-    (B, b, n) block row, args = (u_buf, l_row, u_row) with u_buf the
-    (B, n, n) relay buffer holding the U rows of servers 0..t-1 (zeros
-    below) and t a traced int32. Returns the buffer with server t's U row
-    written in (the one-way hand-off to server t+1) and its strips."""
-    u_buf, l_row, u_row = args  # (B,n,n), (B,b,n), (B,b,n)
-    B = x_row.shape[0]
-    zero = jnp.zeros((), jnp.int32)
-
-    # --- L_{i,k} for k < i (sequential in k; TRSM vs upstream U_kk) ---
-    def lblk(k, l_row):
-        kb = (k * b).astype(jnp.int32)
-        # slice the U column panel FIRST: O(b·n·b) per step instead of
-        # recomputing the full (b,n) product (§Perf C2 — 16x fewer flops
-        # in the L-row loop)
-        u_col = lax.dynamic_slice(u_buf, (zero, zero, kb), (B, n, b))
-        acc = (lax.dynamic_slice(x_row, (zero, zero, kb), (B, b, b))
-               - precise_matmul(l_row, u_col))
-        ukk = lax.dynamic_slice(u_buf, (zero, kb, kb), (B, b, b))
-        lik = _trsm_right_upper_b(ukk, acc)
-        return lax.dynamic_update_slice(l_row, lik, (zero, zero, kb))
-
-    l_row = lax.fori_loop(0, t, lblk, l_row)
-
-    # --- Schur update of the whole row, blocked-panel LU of the diag ---
-    s = x_row - precise_matmul(l_row, u_buf)
-    ib = (t * b).astype(jnp.int32)
-    sii = lax.dynamic_slice(s, (zero, zero, ib), (B, b, b))
-    lii, uii = _factor_diag(sii)
-    l_row = lax.dynamic_update_slice(l_row, lii, (zero, zero, ib))
-
-    # --- U_{i,j} for j >= i, vectorized over the full row ---
-    r = jax.scipy.linalg.solve_triangular(lii, s, lower=True, unit_diagonal=True)
-    cols = lax.broadcasted_iota(jnp.int32, (B, b, n), 2)
-    u_row = jnp.where(cols >= ib, r, jnp.zeros_like(r))
-    u_buf = lax.dynamic_update_slice(u_buf, u_row, (zero, ib, zero))
-    return u_buf, l_row, u_row
-
-
 def _server_program(x_blk: jnp.ndarray, *, n: int, b: int, num_servers: int,
                     axis: str, faults=()) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Runs on every chip inside shard_map. x_blk: (k·b, n) or (B, k·b, n),
-    the block rows of the chip's k = N / chips servers (`per_chip`)."""
+    the block rows of the chip's k = N / chips servers (`per_chip`).
+
+    A loop of N + chips − 1 steps. At step i the chip takes up U row
+    r = i − chip: its own server's turn if r is one of its servers, else
+    the row its predecessor handled at step i − 1, which the step's hop
+    brings. It applies that row to the rows of its servers after r and
+    hands it on at the next step's hop."""
     per_chip = x_blk.shape[-2] // b
     chips = num_servers // per_chip
     my_chip = lax.axis_index(axis)
-    x_slab, batched = _batched_view(x_blk, per_chip * b, n)
-    B = x_slab.shape[0]
+    slab, batched = _batched_view(x_blk, per_chip * b, n)
+    B, kb = slab.shape[0], per_chip * b
     zero = jnp.zeros((), jnp.int32)
+    first = my_chip * per_chip  # server of slot 0
+    server_of = first + lax.broadcasted_iota(jnp.int32, (B, kb, 1), 1) // b
+    cols = lax.broadcasted_iota(jnp.int32, (B, b, n), 2)
+    fwd = [(i, i + 1) for i in range(chips - 1)]  # no edge back to chip 0
 
-    def server_j(j, args):
-        """Slot j of the active chip: server my_chip·k + j. The buffer it
-        writes is the hand-off to slot j+1 on the same chip."""
-        u_buf, l_slab, u_slab = args
-        at = (zero, (j * b).astype(jnp.int32), zero)
-        strip = (u_buf, lax.dynamic_slice(l_slab, at, (B, b, n)),
-                 lax.dynamic_slice(u_slab, at, (B, b, n)))
-        x_row = lax.dynamic_slice(x_slab, at, (B, b, n))
-        u_buf, l_row, u_row = _serve_row(my_chip * per_chip + j, x_row,
-                                         strip, n=n, b=b)
-        return (u_buf, lax.dynamic_update_slice(l_slab, l_row, at),
-                lax.dynamic_update_slice(u_slab, u_row, at))
+    def own_turn(r, slab, l_slab, u_slab):
+        """Server r factors the diagonal block of its fully updated row
+        and solves for its U row."""
+        at = (zero, (r - first) * b, zero)
+        rb = r * b
+        s = lax.dynamic_slice(slab, at, (B, b, n))
+        lii, _ = _factor_diag(lax.dynamic_slice(s, (zero, zero, rb), (B, b, b)))
+        u = jax.scipy.linalg.solve_triangular(lii, s, lower=True, unit_diagonal=True)
+        u = jnp.where(cols >= rb, u, jnp.zeros_like(u))
+        return (u, lax.dynamic_update_slice(l_slab, lii, (zero, at[1], rb)),
+                lax.dynamic_update_slice(u_slab, u, at))
 
-    def active(args):
-        return lax.fori_loop(0, per_chip, server_j, args)
+    def apply(r, u, slab, l_slab):
+        """Rank-b right-looking update of the slab rows of servers after r
+        by U row r: L_{t,r} = S_t[:, r-block] U_rr⁻¹, S_t −= L_{t,r} U_r."""
+        rb = r * b
+        later = server_of > r
+        lik = _trsm_right_upper_b(
+            lax.dynamic_slice(u, (zero, zero, rb), (B, b, b)),
+            lax.dynamic_slice(slab, (zero, zero, rb), (B, kb, b)))
+        lik = jnp.where(later, lik, jnp.zeros_like(lik))
+        l_col = lax.dynamic_slice(l_slab, (zero, zero, rb), (B, kb, b))
+        l_slab = lax.dynamic_update_slice(
+            l_slab, jnp.where(later, lik, l_col), (zero, zero, rb))
+        return slab - precise_matmul(lik, u), l_slab
 
-    def passive(args):
-        return args
+    def step(i, state):
+        slab, l_slab, u_slab, outbox = state
+        # the hop: the row handled at step i - 1 goes one chip down. Nothing
+        # of this step's own turn waits on it.
+        inbox = lax.ppermute(outbox, axis, fwd) if fwd else outbox
+        r = i - my_chip
+        own = (r >= first) & (r < first + per_chip)
+        mine, l_slab, u_slab = lax.cond(
+            own, lambda a: own_turn(r, *a),
+            lambda a: (jnp.zeros_like(outbox), *a[1:]), (slab, l_slab, u_slab))
+        row = jnp.where(own, mine, inbox)
+        slab, l_slab = lax.cond(
+            (r >= 0) & (r < first + per_chip), lambda a: apply(r, *a),
+            lambda a: a[1:], (row, slab, l_slab))
+        return slab, l_slab, u_slab, row
 
-    fwd = [(i, (i + 1) % chips) for i in range(chips)]
-
-    def round_fn(d, state):
-        u_buf, l_row, u_row = state
-        u_buf, l_row, u_row = lax.cond(
-            my_chip == d, active, passive, (u_buf, l_row, u_row)
-        )
-        # one-way relay to the next chip (ring hop; only the d -> d+1 edge
-        # carries fresh data, matching the paper's single send per phase)
-        u_buf = lax.ppermute(u_buf, axis, fwd)
-        return u_buf, l_row, u_row
-
-    u_buf0 = jnp.zeros((B, n, n), dtype=x_slab.dtype)
-    l_row0 = jnp.zeros((B, per_chip * b, n), dtype=x_slab.dtype)
-    u_row0 = jnp.zeros((B, per_chip * b, n), dtype=x_slab.dtype)
+    l_slab = jnp.zeros((B, kb, n), dtype=slab.dtype)
+    u_slab = jnp.zeros((B, kb, n), dtype=slab.dtype)
+    outbox = jnp.zeros((B, b, n), dtype=slab.dtype)
     # carries become device-varying inside the loop; mark them so upfront
-    u_buf0, l_row0, u_row0 = lax.pcast(
-        (u_buf0, l_row0, u_row0), (axis,), to="varying"
-    )
-    _, l_row, u_row = lax.fori_loop(
-        0, chips, round_fn, (u_buf0, l_row0, u_row0)
-    )
+    l_slab, u_slab, outbox = lax.pcast((l_slab, u_slab, outbox), (axis,),
+                                       to="varying")
+    _, l_slab, u_slab, _ = lax.fori_loop(
+        0, num_servers + chips - 1, step, (slab, l_slab, u_slab, outbox))
     if faults:
-        l_row, u_row = _inject_faults(l_row, u_row, my_chip, faults, n=n,
-                                      batched=batched, per_chip=per_chip)
+        l_slab, u_slab = _inject_faults(l_slab, u_slab, my_chip, faults, n=n,
+                                        batched=batched, per_chip=per_chip)
     if not batched:
-        return l_row[0], u_row[0]
-    return l_row, u_row
+        return l_slab[0], u_slab[0]
+    return l_slab, u_slab
 
 
 def _server_program_exact(x_blk: jnp.ndarray, *, n: int, b: int,
@@ -416,8 +395,9 @@ def lu_nserver_shardmap(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Distributed Alg. 3. x: (n, n) or (B, n, n) with n % num_servers == 0.
 
-    program: one of "baseline" (fixed-shape relay), "exact" (paper-exact
-    ragged relay), "stream" (no relay buffer; received rows only). The
+    program: one of "baseline" (streaming wavefront, one U row per hop,
+    any k servers per chip), "exact" (paper-exact ragged relay), "stream"
+    (no relay buffer; received rows only). The
     batch dimension, if present, stays device-local — one wavefront sweep
     factors the whole stack (DESIGN.md §3).
 
@@ -475,10 +455,15 @@ def lu_nserver_shardmap(
 
 
 def pipeline_collective_bytes(n: int, num_servers: int, itemsize: int = 8) -> dict:
-    """Communication model: fixed-shape relay vs the paper's exact volume."""
-    relay = num_servers * n * n * itemsize  # one (n,n) hop per round
+    """Communication model, one server per chip: the relay's U rows vs the
+    paper's exact volume. Each server's (b, n) U row crosses every edge of
+    the chain below it once; the paper sends only its blocks from the
+    diagonal on. (The fixed-shape hop also runs on steps whose edge has no
+    row to carry; those messages are zeros and not counted here.)"""
+    b = n // num_servers
+    relay = num_servers * (num_servers - 1) // 2 * b * n * itemsize
     paper = sum(
-        sum((num_servers - k) for k in range(i + 1)) * (n // num_servers) ** 2
+        sum((num_servers - k) for k in range(i + 1)) * b ** 2
         for i in range(num_servers - 1)
     ) * itemsize
     return {"relay_bytes": relay, "paper_exact_bytes": paper,
